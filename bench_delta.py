@@ -26,7 +26,7 @@ to BENCH_HISTORY.jsonl for the regression sentinel.  The row's workload
 stamp is distinct from bench.py's, so output digests never cross-compare.
 
 Env: BENCH_DELTA_TRIPLES (default 8000, rounded to whole planted rules),
-BENCH_DELTA_MIN_SUPPORT (10), BENCH_BACKEND=cpu pins the CPU proxy,
+BENCH_DELTA_MIN_SUPPORT (10), JAX_PLATFORMS=cpu measures the CPU on purpose,
 BENCH_HISTORY as in bench.py.
 """
 
@@ -41,7 +41,7 @@ import traceback
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from bench import _init_backend, _record_history  # noqa: E402
+from bench import _record_history, require_device  # noqa: E402
 from rdfind_tpu.obs import integrity as obs_integrity  # noqa: E402
 from rdfind_tpu.obs import sentinel as obs_sentinel  # noqa: E402
 
@@ -154,9 +154,9 @@ def _run(n: int, min_support: int, backend: str) -> dict:
 def main():
     n = int(os.environ.get("BENCH_DELTA_TRIPLES", 8_000))
     min_support = int(os.environ.get("BENCH_DELTA_MIN_SUPPORT", 10))
+    device = require_device()
     try:
-        backend = _init_backend()
-        result = _run(n, min_support, backend)
+        result = _run(n, min_support, device["platform"])
     except Exception as e:
         tb = traceback.format_exc(limit=3)
         result = {
@@ -165,6 +165,7 @@ def main():
             "detail": {"error": f"{type(e).__name__}: {e}",
                        "traceback": tb.splitlines()[-3:]},
         }
+    result["device"] = device
     print(json.dumps(result, default=str))
     _record_history(result)
 

@@ -58,7 +58,7 @@ class Heartbeat:
 
 
 def write(directory: str, status: dict, host_index: int = 0) -> None:
-    """One unthrottled heartbeat write (standalone writers, e.g. tpu_watch)."""
+    """One unthrottled heartbeat write (standalone writers and tests)."""
     os.makedirs(directory, exist_ok=True)
     Heartbeat(directory, host_index=host_index).beat(status)
 

@@ -26,17 +26,16 @@ MXU-rate notes (the round-6 rework):
     RDFIND_PLANE_BITS) halve the element again: WK widens to 512 words =
     16384 contraction lanes per K step, so each MXU pass covers twice
     int8's K-dim at the same VMEM budget.  Accumulation stays int32 (still
-    exact); backends without native int4 elements run the same widened-WK
-    grid with int8 elements (bit-identical — the emulation the CPU parity
-    tests exercise, since XLA CPU rejects sub-byte conversion outright).
+    exact).  Native int4 elements are used on TPU kinds listed in
+    cooc.SUBBYTE_PLANE_BITS; elsewhere the same widened-WK grid runs with
+    int8 elements (bit-identical — the emulation the CPU parity tests
+    exercise, since XLA CPU rejects sub-byte conversion outright).
   * **int2 crumb planes** (`unpack_dtype="int2"`, RDFIND_PLANE_BITS=2, the
-    round-12 rung) halve once more: WK 1024 words = 32768 contraction lanes
-    per K step — four times int8's K-dim per MXU pass at the same VMEM
-    budget.  The exactness argument is width-independent: planes are 0/1 in
-    any element type and the accumulator stays int32, so a crumb holding
-    {0, 1} loses nothing against a byte holding {0, 1}.  Backends without
-    native int2 elements keep the quadrupled-WK grid with int8 elements,
-    exactly like the int4 emulation.
+    round-12 rung) keep a quadrupled WK of 1024 words but always store int8
+    elements: Mosaic refuses native int2 matmuls on v5e (`Unsupported type
+    for matmul: 'vector<128x256xi2>'`, AOT compile) and AOT-compiling the
+    plain XLA int2 dot kills the compiler with SIGSEGV, so no int2 element
+    type is reachable.
   * the **dep-tile unpack is hoisted out of the ref-tile grid dimension**:
     the ref (j) dimension revisits the same dep tile nj times, so the shifted
     planes are computed once at j == 0 into a persistent VMEM scratch and
@@ -51,10 +50,11 @@ MXU-rate notes (the round-6 rework):
     dimension is also "arbitrary" because the hoisted scratch carries state
     across it.
   * **explicit K-step pipelining** (RDFIND_EMIT_PIPELINE, the round-12
-    rung): where `pltpu.emit_pipeline` is available (probed — it asserts
-    the TPU backend even under interpret=True, so the probe fails closed
-    on CPU), the K grid dimension moves into a manual inner pipeline: the
-    ref-side packed chunks stay HBM-resident (memory_space=ANY) and the
+    rung): on TPU, where a one-time probe runs `pltpu.emit_pipeline` end
+    to end (it asserts the TPU backend even under interpret=True, so off
+    TPU it is never selected), the K grid dimension moves into a manual
+    inner pipeline: the ref-side packed chunks stay HBM-resident
+    (memory_space=ANY) and the
     pipeline's own double-buffered DMAs overlap each chunk's copy-in with
     the previous chunk's MXU pass, replacing Mosaic's implicit
     "arbitrary"-dimension buffering with an explicitly scheduled one.  The
@@ -65,16 +65,17 @@ MXU-rate notes (the round-6 rework):
 Layout notes (see /opt/skills/guides/pallas_guide.md): Mosaic cannot slice the
 lane dimension at non-128-aligned offsets, so the unpack avoids slicing
 entirely: `pltpu.repeat(x, 32, axis=1)` tiles the packed words 32x along lanes
-(np.tile semantics: lane j holds word j % WK), and the per-lane shift is
-j // WK.  That yields planes in *bit-major* lane order — a fixed permutation of
-the contraction dimension, harmless because both operands unpack identically
-and the dot product is permutation-invariant.  Narrowing casts out of uint32
+(np.tile semantics on the installed jax: lane j holds word j % WK), and the
+per-lane shift is j // WK.  That yields planes in *bit-major* lane order — a
+fixed permutation of the contraction dimension, harmless because both
+operands unpack identically and the dot product is permutation-invariant.  Narrowing casts out of uint32
 go through int32 (Mosaic has no direct uint32->bf16 lowering, r2 bench
 failure; the int8 path keeps the same two-step shape for symmetry).
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
@@ -86,6 +87,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 TILE_D = 128
 TILE_R = 128
+# Host-side dispatch counts per kernel path ("packed_contains",
+# "packed_contains_emit", "fused_verdict", "materialized_verdict"):
+# cooc.resolution_report() publishes them so a run shows which kernels it
+# actually used, not only which ones the policy resolved to.
+KERNELS_USED = collections.Counter()
 # Words per K grid step, by unpack dtype: the unpacked operand tile is
 # (TILE, WK*32) elements in VMEM, so int8's 1-byte planes afford twice the
 # chunk of bf16 at the same budget (256 words = 8192 contraction lanes = 1 MB
@@ -98,9 +104,8 @@ TILE_R = 128
 # stays int32.
 WK_MAX = {"int2": 1024, "int4": 512, "int8": 256, "bf16": 128}
 # Bits per unpacked plane element, keyed by unpack dtype (the VMEM/hoist
-# budget arithmetic; int4/int2 planes may fall back to int8 *elements* on
-# backends without native sub-byte support — see _plane_elem — but keep
-# their widened WK grid either way).
+# budget arithmetic; int4/int2 planes may be stored as int8 *elements* —
+# see _plane_elem — but keep their widened WK grid either way).
 PLANE_ELEM_BITS = {"int2": 2, "int4": 4, "int8": 8, "bf16": 16}
 # VMEM budget for the hoisted full-width dep planes (TILE_D x bits x elem
 # bytes).  4 MB covers bits <= 65536 in int4 / 32768 in int8 / 16384 in
@@ -108,32 +113,6 @@ PLANE_ELEM_BITS = {"int2": 2, "int4": 4, "int8": 8, "bf16": 16}
 # inside the ~16 MB core budget; wider sketches fall back to the per-step
 # unpack.
 HOIST_PLANE_BUDGET = 4 << 20
-
-
-@functools.lru_cache(maxsize=1)
-def _repeat_is_tile() -> bool:
-    """Whether this jax's pltpu.repeat follows np.tile lane order (newer
-    versions: lane j holds word j % WK) or np.repeat order (older: lane j
-    holds word j // n).  The unpack's shift formula must match, or the
-    planes stop being a permutation of the bits and the containment counts
-    go silently wrong.  Probed once through the interpreter, which agrees
-    with the Mosaic lowering within a jax version."""
-    try:
-        def k(x_ref, o_ref):
-            o_ref[:] = pltpu.repeat(x_ref[:], 2, axis=1)
-
-        # The first call can land inside an outer jit/pallas trace (the
-        # kernel is traced lazily); escape it so the probe runs eagerly —
-        # staged, its output would be a tracer and the comparison would
-        # bogusly take the except path.
-        with jax.ensure_compile_time_eval():
-            out = pl.pallas_call(
-                k, out_shape=jax.ShapeDtypeStruct((1, 4), jnp.int32),
-                interpret=True)(jnp.arange(2, dtype=jnp.int32).reshape(1, 2))
-            host = [int(v) for v in np.asarray(out)[0]]
-        return host == [0, 1, 0, 1]
-    except Exception:
-        return True  # current upstream semantics
 
 
 def _default_unpack_dtype() -> str:
@@ -149,60 +128,45 @@ def _default_unpack_dtype() -> str:
 def _plane_elem(dtype: str) -> str:
     """Resolved element type the planes are actually stored/contracted in.
 
-    "int4"/"int2" planes use native sub-byte jnp elements only where the
-    backend's matching matmul lowers (cooc.int4_elements_native /
-    int2_elements_native probes); elsewhere the sub-byte mode keeps its
-    widened-WK grid but stores int8 elements — the arithmetic is identical
-    (0/1 planes, int32 accumulation), so outputs are bit-identical and
-    every mode stays differential-testable on CPU, whose XLA rejects
-    sub-byte conversions outright.  The result is a STATIC jit key
-    alongside unpack_dtype: a probe flip must retrace."""
+    "int4" planes use native jnp.int4 elements where cooc says the chip
+    supports them (cooc.int4_elements_native); elsewhere, and always for
+    "int2", the sub-byte mode keeps its widened-WK grid but stores int8
+    elements — the arithmetic is identical (0/1 planes, int32
+    accumulation), so outputs are bit-identical and every mode stays
+    differential-testable on CPU, whose XLA rejects sub-byte conversions
+    outright.  The result is a STATIC jit key alongside unpack_dtype."""
     from . import cooc
 
     if dtype == "int4":
         return "int4" if cooc.int4_elements_native() else "int8"
     if dtype == "int2":
-        return "int2" if cooc.int2_elements_native() else "int8"
+        return "int8"
     return dtype
 
 
-_PLANE_JNP = {"bf16": jnp.bfloat16, "int8": jnp.int8}
-if hasattr(jnp, "int4"):
-    _PLANE_JNP["int4"] = jnp.int4
-if hasattr(jnp, "int2"):
-    _PLANE_JNP["int2"] = jnp.int2
+_PLANE_JNP = {"bf16": jnp.bfloat16, "int8": jnp.int8, "int4": jnp.int4}
 
 
-def _repeat32(x):
-    """The 32x lane repeat behind the unpack — module-level indirection so
-    tests can substitute a jnp.tile / jnp.repeat emulation of either lane
-    order and exercise both _repeat_is_tile branches on any jax version."""
-    return pltpu.repeat(x, 32, axis=1)
-
-
-def _unpack_tile(x, plane_dt, tile_order: bool):
+def _unpack_tile(x, plane_dt):
     """(TILE, WK) packed uint32 -> (TILE, WK*32) 0/1 planes in `plane_dt`.
 
-    Lane j of the result is bit (j // WK) of word (j % WK) under tile-order
-    repeat, or bit (j % 32) of word (j // 32) under repeat-order — either is
-    a fixed permutation of the bits, harmless because both operands unpack
+    Lane j of the result is bit (j // WK) of word (j % WK) — a fixed
+    permutation of the bits, harmless because both operands unpack
     identically and the dot product is permutation-invariant.  Only
     full-tile ops: repeat, iota, shift, compare — no lane slicing (Mosaic
     requires lane-dim slice offsets to be 128-aligned, which word steps are
     not).
     """
     wk = x.shape[1]
-    rep = _repeat32(x)
+    rep = pltpu.repeat(x, 32, axis=1)
     lane = jax.lax.broadcasted_iota(jnp.uint32, rep.shape, 1)
-    shifts = (jax.lax.div(lane, jnp.uint32(wk)) if tile_order
-              else jax.lax.rem(lane, jnp.uint32(32)))
+    shifts = jax.lax.div(lane, jnp.uint32(wk))
     bits = ((rep >> shifts) & jnp.uint32(1)).astype(jnp.int32)
     return bits.astype(plane_dt)
 
 
 def _contains_kernel(s_ref, r_ref, popc_ref, out_ref, s_plane_ref, acc_ref, *,
-                     nk: int, wk: int, plane_dt, tile_order: bool,
-                     hoist: bool, acc_dt):
+                     nk: int, wk: int, plane_dt, hoist: bool, acc_dt):
     """One (TILE_D, TILE_R) tile of the containment matrix.
 
     s_ref: (TILE_D, WK) packed dep sketches; r_ref: (TILE_R, WK) packed ref bit
@@ -230,13 +194,12 @@ def _contains_kernel(s_ref, r_ref, popc_ref, out_ref, s_plane_ref, acc_ref, *,
 
         @pl.when(j == 0)
         def _fill():
-            s_plane_ref[:, chunk] = _unpack_tile(s_ref[:], plane_dt,
-                                                 tile_order)
+            s_plane_ref[:, chunk] = _unpack_tile(s_ref[:], plane_dt)
 
         s_b = s_plane_ref[:, chunk]
     else:
-        s_b = _unpack_tile(s_ref[:], plane_dt, tile_order)
-    r_b = _unpack_tile(r_ref[:], plane_dt, tile_order)
+        s_b = _unpack_tile(s_ref[:], plane_dt)
+    r_b = _unpack_tile(r_ref[:], plane_dt)
     acc_ref[:] += jax.lax.dot_general(
         s_b, r_b, (((1,), (1,)), ((), ())),
         preferred_element_type=acc_dt)
@@ -246,47 +209,66 @@ def _contains_kernel(s_ref, r_ref, popc_ref, out_ref, s_plane_ref, acc_ref, *,
         out_ref[:] = (acc_ref[:].astype(jnp.int32) == popc_ref[:]).astype(jnp.uint8)
 
 
+class KernelProbeError(RuntimeError):
+    """A kernel-mode probe failed on the TPU.  Raised instead of quietly
+    selecting another kernel, so a chip run never swaps kernels unseen."""
+
+
 @functools.lru_cache(maxsize=1)
 def emit_pipeline_supported() -> bool:
-    """Whether pltpu.emit_pipeline actually traces AND runs here.
+    """Whether pltpu.emit_pipeline traces AND runs here.
 
-    hasattr alone is not a probe: the API exists on every recent jax but
-    asserts the TPU backend at trace time even under interpret=True, so on
-    the CPU proxy a hasattr gate would select a kernel that cannot compile.
-    Instead a minimal two-step accumulation pipeline is run end to end
-    (probe-before-assume, like _repeat_is_tile); any failure — missing
-    API, backend assert, lowering error — falls back to the PR-6
-    "arbitrary"-dimension K grid, which is bit-identical."""
-    if not hasattr(pltpu, "emit_pipeline"):
+    Off TPU it never can (the API asserts the TPU backend at trace time
+    even under interpret=True), so the answer there is False without a
+    probe.  On TPU a minimal two-step accumulation pipeline runs end to
+    end once; a failure or a wrong sum raises KernelProbeError naming the
+    probe — it never falls back in silence."""
+    if jax.default_backend() != "tpu":
         return False
+
+    def kern(x_hbm, o_ref, acc_ref):
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+        def body(x_ref):
+            acc_ref[:] += x_ref[:]
+
+        pltpu.emit_pipeline(
+            body, grid=(2,),
+            in_specs=[pl.BlockSpec((8, 128), lambda k: (k, 0))])(x_hbm)
+        o_ref[:] = acc_ref[:]
+
+    def probe(x):
+        return pl.pallas_call(
+            kern,
+            out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32)],
+        )(x)
+
     try:
-        def kern(x_hbm, o_ref, acc_ref):
-            acc_ref[:] = jnp.zeros_like(acc_ref)
-
-            def body(x_ref):
-                acc_ref[:] += x_ref[:]
-
-            pltpu.emit_pipeline(
-                body, grid=(2,),
-                in_specs=[pl.BlockSpec((8, 128), lambda k: (k, 0))])(x_hbm)
-            o_ref[:] = acc_ref[:]
-
-        with jax.ensure_compile_time_eval():
-            out = pl.pallas_call(
-                kern,
-                out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
-                in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-                out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-                scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32)],
-            )(jnp.ones((16, 128), jnp.float32))
-            return bool(np.asarray(out)[0, 0] == 2.0)
-    except Exception:
-        return False
+        # Lowered and compiled on its own, then run on a host array: the
+        # first call can come from inside an outer jit trace, whose tracers
+        # must not reach (or be captured by) the probe kernel.
+        x = np.ones((16, 128), np.float32)
+        compiled = jax.jit(probe).lower(
+            jax.ShapeDtypeStruct(x.shape, x.dtype)).compile()
+        got = float(np.asarray(compiled(x))[0, 0])
+    except Exception as e:
+        raise KernelProbeError(
+            f"emit_pipeline_supported: the emit_pipeline probe kernel "
+            f"failed on {jax.devices()[0].device_kind}: "
+            f"{type(e).__name__}: {e}") from e
+    if got != 2.0:
+        raise KernelProbeError(
+            f"emit_pipeline_supported: the probe kernel summed to {got}, "
+            f"want 2.0")
+    return True
 
 
 def _contains_kernel_emit(s_ref, r_hbm, popc_ref, out_ref, s_plane_ref,
                           acc_ref, step_ref, *, nk: int, wk: int, plane_dt,
-                          tile_order: bool, hoist: bool, acc_dt):
+                          hoist: bool, acc_dt):
     """The emit-pipeline variant of _contains_kernel: outer grid (i, j)
     only; the K dimension runs as an explicit pltpu.emit_pipeline whose
     double-buffered DMAs stream the packed ref chunks out of HBM
@@ -307,7 +289,7 @@ def _contains_kernel_emit(s_ref, r_hbm, popc_ref, out_ref, s_plane_ref,
         def _fill():
             for kk in range(nk):  # static unroll: one chunk-wide unpack each
                 s_plane_ref[:, kk * wk32:(kk + 1) * wk32] = _unpack_tile(
-                    s_ref[:, kk * wk:(kk + 1) * wk], plane_dt, tile_order)
+                    s_ref[:, kk * wk:(kk + 1) * wk], plane_dt)
 
     def body(r_ref):
         k = step_ref[0]
@@ -319,8 +301,8 @@ def _contains_kernel_emit(s_ref, r_hbm, popc_ref, out_ref, s_plane_ref,
             s_b = s_plane_ref[:, chunk]
         else:
             pchunk = (slice(0, wk) if nk == 1 else pl.ds(k * wk, wk))
-            s_b = _unpack_tile(s_ref[:, pchunk], plane_dt, tile_order)
-        r_b = _unpack_tile(r_ref[:], plane_dt, tile_order)
+            s_b = _unpack_tile(s_ref[:, pchunk], plane_dt)
+        r_b = _unpack_tile(r_ref[:], plane_dt)
         acc_ref[:] += jax.lax.dot_general(
             s_b, r_b, (((1,), (1,)), ((), ())),
             preferred_element_type=acc_dt)
@@ -347,8 +329,9 @@ def packed_contains_matrix(sketch_packed, ref_packed, ref_popc, *,
     in-register plane type ("int8" wherever int8 matmul lowers — the default —
     else "bf16"); every mode is exact and bit-identical.  `emit_pipeline`
     selects the explicit K-step pipeline (default: the resolved
-    RDFIND_EMIT_PIPELINE policy); where the probe says the API cannot run,
-    the request silently degrades to the grid variant — same outputs.
+    RDFIND_EMIT_PIPELINE policy) where the K-step block is a whole number
+    of 128-lane tiles; off TPU, where the API cannot run, and for narrower
+    sketches the request takes the grid variant — same outputs.
     """
     if unpack_dtype is None:
         unpack_dtype = _default_unpack_dtype()
@@ -359,27 +342,31 @@ def packed_contains_matrix(sketch_packed, ref_packed, ref_popc, *,
         from . import cooc
 
         emit_pipeline = cooc.emit_pipeline_enabled()
-    # The pltpu.repeat lane-order probe keys the jit cache, and so do the
-    # resolved plane element type and the emit-pipeline resolution (PR-2's
-    # static-key discipline extended to plane width and K-step schedule): a
-    # monkeypatched or version-dependent flip must retrace the kernel, not
-    # reuse the other mode's program.
+    # The resolved plane element type and the emit-pipeline resolution key
+    # the jit cache (PR-2's static-key discipline extended to plane width
+    # and K-step schedule): a monkeypatched flip must retrace the kernel,
+    # not reuse the other mode's program.
+    # The emit variant DMAs (TILE_R, wk)-word ref blocks itself, and Mosaic
+    # refuses a block narrower than the 128-lane tiling ("Slice shape along
+    # dimension 1 must be aligned to tiling (128), but is 64", chip run,
+    # PR 21); narrower sketches take the grid variant, whose full-width
+    # blocks need no slice.
+    wk = min(sketch_packed.shape[1], WK_MAX[unpack_dtype])
+    emit = bool(emit_pipeline) and wk % 128 == 0 \
+        and emit_pipeline_supported()
+    KERNELS_USED["packed_contains_emit" if emit else "packed_contains"] += 1
     return _packed_contains_matrix(sketch_packed, ref_packed, ref_popc,
                                    interpret=interpret,
                                    unpack_dtype=unpack_dtype,
                                    plane_elem=_plane_elem(unpack_dtype),
-                                   tile_order=_repeat_is_tile(),
-                                   emit=bool(emit_pipeline)
-                                   and emit_pipeline_supported())
+                                   emit=emit)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "unpack_dtype",
-                                             "plane_elem", "tile_order",
-                                             "emit"))
+                                             "plane_elem", "emit"))
 def _packed_contains_matrix(sketch_packed, ref_packed, ref_popc, *,
                             interpret: bool, unpack_dtype: str,
-                            plane_elem: str, tile_order: bool,
-                            emit: bool = False):
+                            plane_elem: str, emit: bool = False):
     d, w = sketch_packed.shape
     r = ref_packed.shape[0]
     wk = min(w, WK_MAX[unpack_dtype])
@@ -396,7 +383,7 @@ def _packed_contains_matrix(sketch_packed, ref_packed, ref_popc, *,
     hoist = TILE_D * w * 32 * elem_bits // 8 <= HOIST_PLANE_BUDGET
     if emit:
         kernel = functools.partial(_contains_kernel_emit, nk=nk, wk=wk,
-                                   plane_dt=plane_dt, tile_order=tile_order,
+                                   plane_dt=plane_dt,
                                    hoist=hoist, acc_dt=acc_dt)
         return pl.pallas_call(
             kernel,
@@ -409,7 +396,7 @@ def _packed_contains_matrix(sketch_packed, ref_packed, ref_popc, *,
                 # pipeline.
                 pl.BlockSpec((TILE_D, w), lambda i, j: (i, 0),
                              memory_space=pltpu.VMEM),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec((1, TILE_R), lambda i, j: (0, j),
                              memory_space=pltpu.VMEM),
             ],
@@ -422,15 +409,13 @@ def _packed_contains_matrix(sketch_packed, ref_packed, ref_popc, *,
             ],
             # j is "arbitrary": the hoisted dep planes carry state across
             # the ref tiles; the K dimension lives inside the kernel now.
-            compiler_params=getattr(pltpu, "CompilerParams",
-                                    getattr(pltpu, "TPUCompilerParams",
-                                            None))(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
         )(sketch_packed, ref_packed, ref_popc.reshape(1, r))
     grid = (d // TILE_D, r // TILE_R, nk)
     kernel = functools.partial(_contains_kernel, nk=nk, wk=wk,
-                               plane_dt=plane_dt, tile_order=tile_order,
+                               plane_dt=plane_dt,
                                hoist=hoist, acc_dt=acc_dt)
     return pl.pallas_call(
         kernel,
@@ -452,13 +437,10 @@ def _packed_contains_matrix(sketch_packed, ref_packed, ref_popc, *,
             pltpu.VMEM((TILE_D, (w if hoist else wk) * 32), plane_dt),
             pltpu.VMEM((TILE_D, TILE_R), acc_dt),
         ],
-        # Renamed upstream (TPUCompilerParams -> CompilerParams); support both
-        # spellings so the kernel loads on old and new jax alike.  j and k are
-        # "arbitrary": j carries the hoisted-scratch state sequentially, and
-        # k's sequential revisiting is what Mosaic double-buffers the K-step
-        # operand DMAs across.
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        # j and k are "arbitrary": j carries the hoisted-scratch state
+        # sequentially, and k's sequential revisiting is what Mosaic
+        # double-buffers the K-step operand DMAs across.
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(sketch_packed, ref_packed, ref_popc.reshape(1, r))
@@ -488,23 +470,14 @@ def _packed_contains_matrix(sketch_packed, ref_packed, ref_popc, *,
 # K-step mechanisms the roofline plan names, this kernel rides the
 # scalar-prefetched grid (its K schedule is data-dependent — the block-id
 # list IS the prefetched scalar, already an explicitly scheduled,
-# double-buffered K loop), while the containment kernel
-# above carries the pltpu.emit_pipeline variant (RDFIND_EMIT_PIPELINE;
-# probed, not assumed, like the pltpu.repeat shim).  Padded schedule
+# double-buffered K loop), while the containment kernel above carries the
+# pltpu.emit_pipeline variant (RDFIND_EMIT_PIPELINE).  Padded schedule
 # entries fetch block 0 and are compute-guarded by the prefetched
 # real-block count.
 # ---------------------------------------------------------------------------
 
 CIND_BLOCK_D = 128
 CIND_BLOCK_R = 128
-
-
-def scalar_prefetch_available() -> bool:
-    """Whether this jax ships the scalar-prefetch grid the fused kernel's
-    K-step schedule rides (probe-before-assume, like the pltpu.repeat
-    shim).  Absent it, the fused path stays off and the materialized
-    sweep runs — no hard dependency on the newer API."""
-    return hasattr(pltpu, "PrefetchScalarGridSpec")
 
 
 def _fused_cind_kernel(bids_ref, nreal_ref, md_ref, mr_ref, sup_ref, ok_ref,
@@ -536,9 +509,10 @@ def _fused_cind_kernel(bids_ref, nreal_ref, md_ref, mr_ref, sup_ref, ok_ref,
         is_cind &= gid_ref[:] != ridx_ref[:]  # no self-pairs
         d_code = dcode_ref[:]
         r_code = rcode_ref[:]
-        implied = cc.is_subcode(r_code, d_code) & jnp.where(
-            cc.first_subcapture(d_code) == r_code,
-            rv1_ref[:] == dv1_ref[:], rv1_ref[:] == dv2_ref[:])
+        first = cc.first_subcapture(d_code) == r_code
+        implied = cc.is_subcode(r_code, d_code) & (
+            (first & (rv1_ref[:] == dv1_ref[:]))
+            | (~first & (rv1_ref[:] == dv2_ref[:])))
         v = is_cind & ~implied
         verdict_ref[:] = v.astype(jnp.uint8)
         row = jnp.sum(v.astype(jnp.int32), axis=1, keepdims=True)
@@ -611,8 +585,7 @@ def fused_cind_blocks(m_dep, m, sup_col, ok_col, gid_col, dcode_col, dv1_col,
         # i is parallel; j carries the popc accumulation and k the VMEM
         # count accumulator, both sequential ("arbitrary") — which is also
         # what lets Mosaic double-buffer the K-step operand DMAs.
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(block_ids, n_real, m_dep, m, sup_col, ok_col, gid_col, dcode_col,

@@ -367,8 +367,8 @@ def kernel_selfcheck(n_rows: int = 1024, n_bits: int = 4096,
     result = {"parity": parity, "n_rows": n_rows, "bits": n_bits,
               "backend": backend,
               # The resolved kernel mode this selfcheck actually ran — the
-              # provenance the bench kernel-mode rows and tpu_watch capture
-              # key on (one row per knob set is meaningless without it).
+              # provenance the bench kernel-mode rows key on (one row per
+              # knob set is meaningless without it).
               "kernel_dtype": _kernel_dtype(),
               "plane_bits": cooc.resolved_plane_bits(),
               "emit_pipeline": cooc.emit_pipeline_enabled()}

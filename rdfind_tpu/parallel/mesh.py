@@ -120,22 +120,13 @@ def dcn_chunks_auto(report) -> int:
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs, check_vma=False):
-    """Version-portable shard_map: the ONE entry point for every sharded
-    program in this repo.
+    """The ONE entry point for every sharded program in this repo.
 
-    Newer jax exposes `jax.shard_map` with the replication check named
-    `check_vma`; 0.4.x only has `jax.experimental.shard_map.shard_map` with
-    the same flag named `check_rep`.  The pipelines disable the check either
-    way (their collective programs trip its conservative replication
-    inference), so the flag just needs to reach whichever spelling exists.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    The pipelines disable the replication check (their collective programs
+    trip its conservative inference), so every call site goes through this
+    one default."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def link_probe_enabled() -> bool:

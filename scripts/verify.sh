@@ -156,7 +156,7 @@ fi
 
 echo "== tiny bench -> BENCH_HISTORY -> regression sentinel =="
 hist="${BENCH_HISTORY:-BENCH_HISTORY.jsonl}"
-if ! BENCH_BACKEND=cpu JAX_PLATFORMS=cpu \
+if ! JAX_PLATFORMS=cpu \
      BENCH_TRIPLES="${VERIFY_BENCH_TRIPLES:-400}" BENCH_MIN_SUPPORT=2 \
      BENCH_PIPELINE_TRIPLES=600 BENCH_EXCHANGE_TRIPLES=600 \
      BENCH_HISTORY="$hist" \
@@ -173,7 +173,7 @@ echo "== tiny delta bench -> BENCH_HISTORY -> regression sentinel =="
 # the proportional-to-change claim, regression-gated like every other
 # metric.  Appends to the SAME history file; the rows carry a distinct
 # workload stamp so output digests never cross-compare with bench.py's.
-if ! BENCH_BACKEND=cpu JAX_PLATFORMS=cpu \
+if ! JAX_PLATFORMS=cpu \
      BENCH_DELTA_TRIPLES="${VERIFY_BENCH_DELTA_TRIPLES:-1200}" \
      BENCH_HISTORY="$hist" \
      timeout -k 10 1800 python bench_delta.py > /tmp/_verify_bench_delta.json; then
@@ -188,7 +188,7 @@ echo "== serve bench -> BENCH_HISTORY -> regression sentinel =="
 # Query-plane rows (serve_qps / serve_open_ms / serve_p99_us): the mmap'd
 # index's open must stay O(header) and holds() must stay >= the QPS floor;
 # regressions gate like kernel regressions.
-if ! BENCH_BACKEND=cpu JAX_PLATFORMS=cpu \
+if ! JAX_PLATFORMS=cpu \
      BENCH_HISTORY="$hist" \
      timeout -k 10 900 python bench_serve.py > /tmp/_verify_bench_serve.json; then
     echo "verify: serve bench FAILED (see /tmp/_verify_bench_serve.json)" >&2

@@ -82,11 +82,10 @@ def test_resolved_dtype_policy(monkeypatch):
     monkeypatch.setattr(cooc, "COOC_DTYPE", "int8")
     assert cooc.resolved_cooc_dtype() == "int8"
     monkeypatch.setattr(cooc, "COOC_DTYPE", "auto")
-    # auto = int8 only where the hardware int8 path pays off (TPU MXU);
-    # XLA CPU's generic int8 loops are slower than bf16, so the CPU proxy
-    # resolves bf16 and its wall clock cannot regress.
+    # auto = int8 on the TPU MXU; XLA CPU's generic int8 loops are slower
+    # than bf16, so the CPU resolves bf16 and its wall clock cannot regress.
     assert cooc.resolved_cooc_dtype() == (
-        "int8" if cooc._int8_pays_off() else "bf16")
+        "int8" if cooc._on_tpu() else "bf16")
     import jax
     if jax.default_backend() != "tpu":
         assert cooc.resolved_cooc_dtype() == "bf16"
@@ -132,10 +131,8 @@ def test_strategies_invariant_to_dtype_and_schedule(monkeypatch, dtype,
 
 
 def test_plane_bits_resolution_policy(monkeypatch):
-    # Explicit pins are honored; "auto" narrows to the NARROWEST sub-byte
-    # mode whose MXU path pays off (2, else 4, else 8), mirroring the
-    # _int8_pays_off discipline — the CPU proxy stays on 8-bit planes and
-    # cannot regress.
+    # Explicit pins are honored; "auto" takes the chip kind's entry of
+    # SUBBYTE_PLANE_BITS on TPU and 8-bit planes everywhere else.
     monkeypatch.setattr(cooc, "PLANE_BITS", "8")
     assert cooc.resolved_plane_bits() == 8
     monkeypatch.setattr(cooc, "PLANE_BITS", "4")
@@ -144,7 +141,7 @@ def test_plane_bits_resolution_policy(monkeypatch):
     assert cooc.resolved_plane_bits() == 2
     monkeypatch.setattr(cooc, "PLANE_BITS", "auto")
     assert cooc.resolved_plane_bits() == (
-        2 if cooc._int2_pays_off() else 4 if cooc._int4_pays_off() else 8)
+        cooc.tpu_plane_bits() if cooc._on_tpu() else 8)
     # The kernel dtype narrows to int4/int2 only on int8 membership: the
     # bf16 fallback keeps its own planes.
     monkeypatch.setattr(cooc, "COOC_DTYPE", "int8")

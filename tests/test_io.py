@@ -163,3 +163,21 @@ def test_is_utf8_aliases():
     assert not reader.is_utf8("no-such-codec")
     assert not reader.is_utf8({"a.nt": "utf-8"})
     assert not reader.is_utf8(None)
+
+
+@pytest.mark.parametrize("high", [1000, 10 ** 10])
+def test_write_nt_matches_token_spelling(tmp_path, high):
+    """The vectorized writer spells every row exactly as
+    synth.triples_to_tokens does; ids past nine digits take the per-row
+    path."""
+    import numpy as np
+
+    from rdfind_tpu.utils import synth
+
+    t = np.random.default_rng(1).integers(0, high, (300, 3))
+    path = tmp_path / "t.nt"
+    synth.write_nt(str(path), t, chunk_rows=128)
+    want = "".join(f"{s} {p} {o} .\n"
+                   for s, p, o in synth.triples_to_tokens(t))
+    assert path.read_text() == want
+

@@ -5,9 +5,10 @@ report surfaces.
 
 Everything here runs on the CPU proxy: emit_pipeline cannot trace off TPU
 (even interpreted) and XLA CPU rejects int2/int4 custom element types, so
-the sub-byte and emit rows exercise exactly the fallback paths production
-would take on this backend — which is the contract under test.  The native
-rows are captured by tpu_watch on the real chip.
+the sub-byte and emit rows exercise exactly the paths production would
+take on this backend — which is the contract under test.  The native rows
+run on the chip in chip_smoke.py and are compiled for v5e in
+tests/test_tpu_compile.py.
 """
 
 import numpy as np
@@ -115,18 +116,39 @@ def test_emit_pipeline_knob_resolution(monkeypatch):
     assert cooc.emit_pipeline_enabled() == (jax.default_backend() == "tpu")
 
 
-def test_emit_probe_fails_closed_on_cpu():
-    """The real probe on this backend: emit_pipeline cannot trace off TPU,
-    so the cached verdict must be False (never an exception)."""
+def test_emit_probe_is_false_off_tpu():
+    """Off TPU emit_pipeline cannot trace, so the verdict is False without
+    running a probe (never an exception)."""
     assert pallas_kernels.emit_pipeline_supported() is False
 
 
-def test_int2_probe_fails_closed_on_cpu():
-    """XLA CPU rejects int2 element types; the probe must say so quietly
-    and the auto policy must not narrow past what lowers."""
-    assert cooc.int2_matmul_supported() is False
-    assert cooc._int2_pays_off() is False
-    assert not cooc.int2_elements_native()
+def test_emit_probe_failure_raises_on_tpu(monkeypatch):
+    """On TPU a failing emit_pipeline probe names itself instead of quietly
+    selecting the grid kernel.  Steered here by reporting the backend as
+    TPU: the probe kernel then really runs, and fails, on the CPU."""
+    monkeypatch.setattr(pallas_kernels.jax, "default_backend",
+                        lambda: "tpu")
+    pallas_kernels.emit_pipeline_supported.cache_clear()
+    try:
+        with pytest.raises(pallas_kernels.KernelProbeError,
+                           match="emit_pipeline_supported"):
+            pallas_kernels.emit_pipeline_supported()
+    finally:
+        pallas_kernels.emit_pipeline_supported.cache_clear()
+
+
+def test_int2_planes_never_native():
+    """Mosaic refuses native int2 matmuls on v5e and the XLA int2 dot
+    crashes its compiler: the crumb mode always stores int8 elements."""
+    assert pallas_kernels._plane_elem("int2") == "int8"
+    assert "int2" not in pallas_kernels._PLANE_JNP
+
+
+def test_unknown_tpu_kind_has_no_plane_width():
+    """A device kind missing from SUBBYTE_PLANE_BITS is an error, not a
+    default (the CPU's kind is never in the table)."""
+    with pytest.raises(RuntimeError, match="SUBBYTE_PLANE_BITS"):
+        cooc.tpu_plane_bits()
 
 
 def test_probe_flip_retraces_via_static_keys(monkeypatch):
@@ -136,19 +158,18 @@ def test_probe_flip_retraces_via_static_keys(monkeypatch):
     calls = []
     real = pallas_kernels._packed_contains_matrix
 
-    def spy(s, r, p, *, interpret, unpack_dtype, plane_elem, tile_order,
-            emit=False):
+    def spy(s, r, p, *, interpret, unpack_dtype, plane_elem, emit=False):
         calls.append(emit)
         return real(s, r, p, interpret=interpret, unpack_dtype=unpack_dtype,
-                    plane_elem=plane_elem, tile_order=tile_order, emit=emit)
+                    plane_elem=plane_elem, emit=emit)
 
     monkeypatch.setattr(pallas_kernels, "_packed_contains_matrix", spy)
     rng = np.random.default_rng(23)
     sketches = jnp.asarray(
-        rng.integers(0, 1 << 32, size=(128, 8), dtype=np.uint32))
+        rng.integers(0, 1 << 32, size=(128, 128), dtype=np.uint32))
     from rdfind_tpu.ops import sketch
     ref_packed, popc = sketch.pack_ref_bits(
-        jnp.asarray(rng.integers(0, 100, 128, dtype=np.int32)), bits=256,
+        jnp.asarray(rng.integers(0, 100, 128, dtype=np.int32)), bits=4096,
         num_hashes=4)
 
     monkeypatch.setattr(pallas_kernels, "emit_pipeline_supported",
@@ -217,3 +238,39 @@ def test_resolution_report_struct_and_debug_line(monkeypatch):
     text = "\n".join(obs_report.format_debug_lines(stats))
     assert "kernel=bf16/bf16" in text
     assert "emit=0" in text
+
+
+def test_emit_needs_lane_aligned_blocks(monkeypatch):
+    """The emit variant DMAs (128, wk)-word ref blocks itself; Mosaic
+    refuses wk < 128, so 2048-bit sketches (64 words) take the grid
+    variant even where emit_pipeline is selected, and 4096-bit ones take
+    the emit variant."""
+    from rdfind_tpu.ops import sketch
+
+    calls = []
+    monkeypatch.setattr(pallas_kernels, "emit_pipeline_supported",
+                        lambda: True)
+    monkeypatch.setattr(pallas_kernels, "_packed_contains_matrix",
+                        lambda *a, emit, **k: calls.append(emit))
+    rng = np.random.default_rng(2)
+    for bits in (2048, 4096):
+        ref_packed, popc = sketch.pack_ref_bits(
+            jnp.asarray(rng.integers(0, 100, 128, dtype=np.int32)),
+            bits=bits, num_hashes=4)
+        pallas_kernels.packed_contains_matrix(
+            jnp.zeros((128, bits // 32), jnp.uint32), ref_packed, popc,
+            interpret=True, unpack_dtype="int8", emit_pipeline=True)
+    assert calls == [False, True]
+
+
+@pytest.mark.parametrize("dtype", ["int8", "bf16"])
+def test_block_counts_match_reshape_sum(dtype):
+    """The MXU block-count reduction equals the plain reshape-and-sum."""
+    rng = np.random.default_rng(3)
+    kl, tile = 256, 128
+    member = rng.random((4 * kl, 3 * tile)) < 0.05
+    member[:kl, tile:2 * tile] = False  # one empty block
+    m = jnp.asarray(member, jnp.int8 if dtype == "int8" else jnp.bfloat16)
+    got = np.asarray(cooc._stage_block_counts(m, kl=kl, tile=tile))
+    want = member.reshape(4, kl, 3, tile).sum(axis=(1, 3))
+    np.testing.assert_array_equal(got, want)

@@ -1,13 +1,11 @@
 """Pallas packed-containment kernel vs. the jnp planes formulation.
 
-Runs the kernel in interpreter mode (CPU); the lowered TPU path is exercised by
-bench runs on the real chip.  Parity is checked for ALL unpack dtypes (int8 —
-the default wherever int8 matmul lowers — the int4 nibble and int2 crumb
-sub-byte modes, and the bf16 fallback) under BOTH pltpu.repeat lane-order
-branches, with the matching repeat semantics emulated via monkeypatch so each
-shift formula is exercised on every jax version, and across the emit_pipeline
-knob (off-TPU its =True rows run the probe-refusal fallback — the contract
-that forcing a knob never changes outputs).
+Runs the kernel in interpreter mode (CPU); the lowered TPU path is compiled
+for v5e in tests/test_tpu_compile.py and run on the chip by chip_smoke.py.
+Parity is checked for ALL unpack dtypes (int8 — the TPU default — the int4
+nibble and int2 crumb sub-byte modes, and the bf16 fallback), and across the
+emit_pipeline knob (off-TPU its =True rows run the grid variant — the
+contract that forcing a knob never changes outputs).
 """
 
 import numpy as np
@@ -25,27 +23,10 @@ def random_sketches(rng, n, bits):
     return rng.integers(0, 1 << 32, size=(n, bits // 32), dtype=np.uint32)
 
 
-def force_repeat_order(monkeypatch, tile_order: bool):
-    """Pin the unpack's lane-order branch AND the matching repeat semantics.
-
-    _repeat_is_tile selects the shift formula; _repeat32 is the lane repeat
-    itself.  Forcing one without the other would (correctly) break parity —
-    the pair must agree, and emulating both orders with jnp.tile/jnp.repeat
-    makes each branch testable regardless of the installed pltpu semantics.
-    """
-    monkeypatch.setattr(pallas_kernels, "_repeat_is_tile", lambda: tile_order)
-    monkeypatch.setattr(
-        pallas_kernels, "_repeat32",
-        (lambda x: jnp.tile(x, (1, 32))) if tile_order
-        else (lambda x: jnp.repeat(x, 32, axis=1)))
-
-
-@pytest.mark.parametrize("tile_order", [True, False])
 @pytest.mark.parametrize("unpack_dtype", ["int2", "int4", "int8", "bf16"])
 @pytest.mark.parametrize("seed,bits", [(0, BITS), (1, BITS), (0, 16384),
                                        (2, 32768)])
-def test_packed_kernel_matches_jnp(monkeypatch, seed, bits, unpack_dtype,
-                                   tile_order):
+def test_packed_kernel_matches_jnp(seed, bits, unpack_dtype):
     # bits=16384 -> W=512 words > the int8/bf16 WK_MAX entries, exercising
     # the K-grid accumulation (scratch init at k==0, finalize at k==nk-1)
     # with nk >= 2 plus the hoisted dep-plane chunk writes at dynamic K
@@ -56,7 +37,6 @@ def test_packed_kernel_matches_jnp(monkeypatch, seed, bits, unpack_dtype,
     # int4/int2 elements the sub-byte modes run their widened-WK grids
     # with int8 elements — the documented emulation, same arithmetic, so
     # parity must hold everywhere.
-    force_repeat_order(monkeypatch, tile_order)
     rng = np.random.default_rng(seed)
     d, r = 128, 128
     sketches = random_sketches(rng, d, bits)
@@ -71,12 +51,10 @@ def test_packed_kernel_matches_jnp(monkeypatch, seed, bits, unpack_dtype,
     np.testing.assert_array_equal(got.astype(bool), want)
 
 
-@pytest.mark.parametrize("tile_order", [True, False])
-def test_packed_kernel_int2_multi_k(monkeypatch, tile_order):
+def test_packed_kernel_int2_multi_k():
     # bits=65536 -> W=2048 words: past even int2's quadrupled WK=1024, so
     # the crumb mode runs a genuine nk=2 K-grid (accumulating scratch +
     # dynamic-offset hoisted chunks) rather than a single widened step.
-    force_repeat_order(monkeypatch, tile_order)
     rng = np.random.default_rng(4)
     bits, d, r = 65536, 128, 128
     sketches = random_sketches(rng, d, bits)

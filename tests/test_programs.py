@@ -351,23 +351,3 @@ def test_rdfind_profile_dir(tmp_path):
     dumped = list(prof.rglob("*.xplane.pb")) + list(prof.rglob("*.json.gz"))
     assert dumped, f"no trace artifacts under {prof}"
 
-
-def test_tpu_watch_backend_check():
-    """The watcher must key on the line's OWN backend, not any substring: a
-    CPU-fallback line embedding the prior TPU artifact must not pass."""
-    import json
-    import importlib.util
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "tpu_watch", os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "tpu_watch.py"))
-    watch = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(watch)
-    tpu = json.dumps({"value": 1, "detail": {"backend": "tpu"}})
-    fallback = json.dumps({"value": 1, "detail": {
-        "backend": "cpu",
-        "tpu_headline_artifact": {"detail": {"backend": "tpu"}}}})
-    assert watch.is_tpu_bench_line(tpu)
-    assert not watch.is_tpu_bench_line(fallback)
-    assert not watch.is_tpu_bench_line("not json")
-    assert not watch.is_tpu_bench_line(json.dumps(["backend", "tpu"]))
