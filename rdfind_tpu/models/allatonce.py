@@ -37,7 +37,7 @@ import numpy as np
 
 from .. import conditions as cc
 from ..data import CindTable
-from ..obs import datastats, integrity, metrics
+from ..obs import datastats, integrity, metrics, tracer
 from ..ops import cooc, frequency, minimality, pairs, segments
 from ..ops.emission import emit_join_candidates
 
@@ -55,6 +55,29 @@ def _pad_np(arr: np.ndarray, capacity: int, fill) -> np.ndarray:
     return np.concatenate([arr, np.full(capacity - arr.shape[0], fill, arr.dtype)])
 
 
+def upload_triples(triples: np.ndarray):
+    """The (N, 3) int32 triple table padded to a power-of-two row count (so
+    compiled stages are reused across inputs) and put on the device."""
+    n = triples.shape[0]
+    with tracer.span("upload-triples", cat=tracer.CAT_DISPATCH):
+        return jnp.asarray(np.pad(
+            triples, ((0, segments.pow2_capacity(n) - n), (0, 0)),
+            constant_values=np.iinfo(np.int32).max))
+
+
+def _emit_candidates(triples, n_valid, min_support, projections, use_fc_filter,
+                     use_ars):
+    """The frequent-condition prefilter and candidate emission shared by
+    _stage_candidates and _stage_prepare, each under its device scope."""
+    with jax.named_scope(tracer.SCOPE_FREQUENT_CONDITIONS):
+        valid_t = jnp.arange(triples.shape[0], dtype=jnp.int32) < n_valid
+        freq = (frequency.triple_frequencies(triples, valid_t, min_support,
+                                             find_ar_implied=use_ars)
+                if use_fc_filter else frequency.no_filter(valid_t))
+    with jax.named_scope(tracer.SCOPE_EMIT_CANDIDATES):
+        return emit_join_candidates(triples, freq, projections)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("projections", "use_fc_filter", "use_ars"))
 def _stage_candidates(triples, n_valid, min_support, *, projections, use_fc_filter,
@@ -64,22 +87,20 @@ def _stage_candidates(triples, n_valid, min_support, *, projections, use_fc_filt
     Returns (line_val, line_cap, n_rows, cap_code, cap_v1, cap_v2, num_caps); all
     arrays have capacity 3*|projections|*N with valid data compacted to the front.
     """
-    n = triples.shape[0]
-    valid_t = jnp.arange(n, dtype=jnp.int32) < n_valid
-    freq = (frequency.triple_frequencies(triples, valid_t, min_support,
-                                         find_ar_implied=use_ars)
-            if use_fc_filter else frequency.no_filter(valid_t))
-    cands = emit_join_candidates(triples, freq, projections)
+    cands = _emit_candidates(triples, n_valid, min_support, projections,
+                             use_fc_filter, use_ars)
 
     # Intern captures: (code, v1, v2) -> dense capture id; table in canonical
     # (code, v1, v2) sorted order, matching the reference's Condition.compare.
-    (cap_cols, _, cap_id, num_caps) = segments.masked_unique(
-        [cands.code, cands.v1, cands.v2], cands.valid)
+    with jax.named_scope(tracer.SCOPE_INTERN_CAPTURES):
+        (cap_cols, _, cap_id, num_caps) = segments.masked_unique(
+            [cands.code, cands.v1, cands.v2], cands.valid)
 
     # Join lines: distinct (join value, capture) occurrences, sorted by value.
-    cap_id_keyed = jnp.where(cands.valid, cap_id, SENTINEL)
-    (line_cols, _, _, n_rows) = segments.masked_unique(
-        [cands.join_val, cap_id_keyed], cands.valid)
+    with jax.named_scope(tracer.SCOPE_LINE_IDS):
+        cap_id_keyed = jnp.where(cands.valid, cap_id, SENTINEL)
+        (line_cols, _, _, n_rows) = segments.masked_unique(
+            [cands.join_val, cap_id_keyed], cands.valid)
 
     return (line_cols[0], line_cols[1], n_rows,
             cap_cols[0], cap_cols[1], cap_cols[2], num_caps)
@@ -120,15 +141,14 @@ def _stage_prepare(triples, n_valid, min_support, *, projections, use_fc_filter,
     Returns (line_gid, cap_id, valid, n_lines, cap_code, cap_v1, cap_v2,
     num_caps) at candidate-row capacity.
     """
-    n = triples.shape[0]
-    valid_t = jnp.arange(n, dtype=jnp.int32) < n_valid
-    freq = (frequency.triple_frequencies(triples, valid_t, min_support,
-                                         find_ar_implied=use_ars)
-            if use_fc_filter else frequency.no_filter(valid_t))
-    cands = emit_join_candidates(triples, freq, projections)
-    (cap_cols, _, cap_id, num_caps) = segments.masked_unique(
-        [cands.code, cands.v1, cands.v2], cands.valid)
-    line_gid, n_lines = segments.masked_dense_ids(cands.join_val, cands.valid)
+    cands = _emit_candidates(triples, n_valid, min_support, projections,
+                             use_fc_filter, use_ars)
+    with jax.named_scope(tracer.SCOPE_INTERN_CAPTURES):
+        (cap_cols, _, cap_id, num_caps) = segments.masked_unique(
+            [cands.code, cands.v1, cands.v2], cands.valid)
+    with jax.named_scope(tracer.SCOPE_LINE_IDS):
+        line_gid, n_lines = segments.masked_dense_ids(cands.join_val,
+                                                      cands.valid)
     return (line_gid, cap_id, cands.valid, n_lines,
             cap_cols[0], cap_cols[1], cap_cols[2], num_caps)
 
@@ -149,12 +169,13 @@ def _stage_membership(line_gid, cap_id, valid, min_support, *, l_pad, c_pad,
     lens[l] = frequent captures in line l (matvec against the frequency mask),
     matching the chunked path's per-line pair accounting.
     """
-    m = cooc.build_membership(line_gid, cap_id, valid, l_pad=l_pad,
-                              c_pad=c_pad, dtype=membership_dtype)
-    acc = jnp.int32 if m.dtype == jnp.int8 else jnp.float32
-    dep_count = jnp.sum(m, axis=0, dtype=acc).astype(jnp.int32)
-    freq_mask = (dep_count >= min_support).astype(m.dtype)
-    lens = cooc.cooc_dot(m, freq_mask, dims=((1,), (0,)))
+    with jax.named_scope(tracer.SCOPE_MEMBERSHIP):
+        m = cooc.build_membership(line_gid, cap_id, valid, l_pad=l_pad,
+                                  c_pad=c_pad, dtype=membership_dtype)
+        acc = jnp.int32 if m.dtype == jnp.int8 else jnp.float32
+        dep_count = jnp.sum(m, axis=0, dtype=acc).astype(jnp.int32)
+        freq_mask = (dep_count >= min_support).astype(m.dtype)
+        lens = cooc.cooc_dot(m, freq_mask, dims=((1,), (0,)))
     return m, dep_count, lens
 
 
@@ -295,9 +316,7 @@ def prepare_join_lines(triples, min_support, projections,
     n = triples.shape[0]
     if n == 0 or not any(ch in projections for ch in "spo"):
         return None
-    cap_n = segments.pow2_capacity(n)
-    padded = jnp.asarray(np.pad(triples, ((0, cap_n - n), (0, 0)),
-                                constant_values=np.iinfo(np.int32).max))
+    padded = upload_triples(triples)
     (line_val, line_cap, n_rows, cap_code_d, cap_v1_d, cap_v2_d, num_caps) = \
         _stage_candidates(padded, jnp.int32(n), jnp.int32(min_support),
                           projections=projections,
@@ -374,7 +393,10 @@ def _discover_dense(triples, padded, n, min_support, projections, use_fc_filter,
      num_caps_d) = _stage_prepare(
         padded, jnp.int32(n), jnp.int32(min_support), projections=projections,
         use_fc_filter=use_fc_filter, use_ars=use_ars)
-    n_lines, num_caps = (int(x) for x in jax.device_get((n_lines_d, num_caps_d)))
+    # The first pull waits for the whole join-line build.
+    with tracer.span("pull-prepare-counts", cat=tracer.CAT_PULL):
+        n_lines, num_caps = (int(x) for x in
+                             jax.device_get((n_lines_d, num_caps_d)))
     if n_lines == 0 or num_caps == 0:
         return CindTable.empty()
     plan = cooc.dense_plan(n_lines, num_caps)
@@ -400,7 +422,8 @@ def _discover_dense(triples, padded, n, min_support, projections, use_fc_filter,
         # Two-dispatch pair extraction: pull the exact CIND count (8 bytes,
         # fused into the main dispatch), then pull only that many (dep, ref)
         # indices — never the bit matrix (cooc.extract_packed's rationale).
-        n_cinds = int(jax.device_get(n_bits))
+        with tracer.span("pull-cind-count", cat=tracer.CAT_PULL):
+            n_cinds = int(jax.device_get(n_bits))
         pulls = [jax.lax.slice(lens, (0,), (n_lines,)),
                  jax.lax.slice(dep_count, (0,), (num_caps,)),
                  cap_code[:num_caps], cap_v1[:num_caps], cap_v2[:num_caps]]
@@ -411,8 +434,9 @@ def _discover_dense(triples, padded, n, min_support, projections, use_fc_filter,
                 cap=segments.pow2_capacity(n_cinds))
         else:
             pulls += [np.zeros(0, np.int32)] * 2
-        (lens_h, dep_count_h, code_h, v1_h, v2_h, dep_id, ref_id) = \
-            jax.device_get(pulls)
+        with tracer.span("pull-cind-ids", cat=tracer.CAT_PULL):
+            (lens_h, dep_count_h, code_h, v1_h, v2_h, dep_id, ref_id) = \
+                jax.device_get(pulls)
         lens_h = lens_h.astype(np.int64)
         dep_id = dep_id[:n_cinds].astype(np.int64)
         ref_id = ref_id[:n_cinds].astype(np.int64)
@@ -422,40 +446,46 @@ def _discover_dense(triples, padded, n, min_support, projections, use_fc_filter,
             line_gid, cap_id, cand_valid, jnp.int32(min_support),
             membership_dtype=plan.dtype,
             l_pad=l_pad, c_pad=c_pad)
-        lens_h = np.asarray(jax.lax.slice(lens, (0,), (n_lines,)), np.int64)
+        with tracer.span("pull-line-lengths", cat=tracer.CAT_PULL):
+            lens_h = np.asarray(jax.lax.slice(lens, (0,), (n_lines,)),
+                                np.int64)
         dep_id, ref_id, support = cooc.discover_pairs_dense(
             m, dep_count, _fit_device(cap_code, c_pad),
             _fit_device(cap_v1, c_pad), _fit_device(cap_v2, c_pad),
             min_support, num_caps, tile, starts=plan.dep_tile_starts,
             plan=plan, stats=stats)
-        (code_h, v1_h, v2_h, dep_count_h) = jax.device_get(
-            (cap_code[:num_caps], cap_v1[:num_caps], cap_v2[:num_caps],
-             jax.lax.slice(dep_count, (0,), (num_caps,))))
+        with tracer.span("pull-capture-table", cat=tracer.CAT_PULL):
+            (code_h, v1_h, v2_h, dep_count_h) = jax.device_get(
+                (cap_code[:num_caps], cap_v1[:num_caps], cap_v2[:num_caps],
+                 jax.lax.slice(dep_count, (0,), (num_caps,))))
 
-    total_pairs = int((lens_h * (lens_h - 1)).sum())
-    if stats is not None:
-        # Stat semantics match the chunked backend: n_lines counts lines that
-        # kept >= 1 frequent capture, n_line_rows the deduped (value, capture)
-        # rows (= total memberships, the column-sum total of M).
-        metrics.set_many(
-            stats, n_triples=n, n_frequent_rows=int(lens_h.sum()),
-            n_line_rows=int(np.asarray(dep_count_h, np.int64).sum()),
-            n_lines=int((lens_h > 0).sum()), n_captures=num_caps,
-            total_pairs=total_pairs,
-            max_line=int(lens_h.max()) if n_lines else 0,
-            pair_backend="matmul")
-        if datastats.enabled():
-            datastats.publish_line_stats(
-                stats, hist=datastats.log2_bucket_counts(lens_h),
-                n_lines=int((lens_h > 0).sum()),
+    # Host reductions over the per-line vector, while the device idles.
+    with tracer.span("line-stats", cat=tracer.CAT_STAGE):
+        total_pairs = int((lens_h * (lens_h - 1)).sum())
+        if stats is not None:
+            # Stat semantics match the chunked backend: n_lines counts lines
+            # that kept >= 1 frequent capture, n_line_rows the deduped
+            # (value, capture) rows (= total memberships, the column-sum
+            # total of M).
+            metrics.set_many(
+                stats, n_triples=n, n_frequent_rows=int(lens_h.sum()),
+                n_line_rows=int(np.asarray(dep_count_h, np.int64).sum()),
+                n_lines=int((lens_h > 0).sum()), n_captures=num_caps,
+                total_pairs=total_pairs,
                 max_line=int(lens_h.max()) if n_lines else 0,
-                source="single")
-            sup = np.asarray(dep_count_h, np.int64)
-            datastats.publish_capture_spectrum(
-                stats, hist=datastats.log2_bucket_counts(sup),
-                n_captures=num_caps,
-                max_support=int(sup.max()) if sup.size else 0,
-                source="single")
+                pair_backend="matmul")
+            if datastats.enabled():
+                datastats.publish_line_stats(
+                    stats, hist=datastats.log2_bucket_counts(lens_h),
+                    n_lines=int((lens_h > 0).sum()),
+                    max_line=int(lens_h.max()) if n_lines else 0,
+                    source="single")
+                sup = np.asarray(dep_count_h, np.int64)
+                datastats.publish_capture_spectrum(
+                    stats, hist=datastats.log2_bucket_counts(sup),
+                    n_captures=num_caps,
+                    max_support=int(sup.max()) if sup.size else 0,
+                    source="single")
     if dep_id.size == 0:
         return CindTable.empty()
     table = CindTable(
@@ -472,14 +502,16 @@ def _discover_dense(triples, padded, n, min_support, projections, use_fc_filter,
 
 
 def _postprocess(table, triples, min_support, use_ars, clean_implied, stats):
-    if use_ars:
-        rules = frequency.mine_association_rules(triples, min_support)
-        if stats is not None:
-            metrics.struct_set(stats, "association_rules", rules)
-        table = filter_ar_implied_cinds(table, rules)
-    if clean_implied:
-        table = minimality.minimize_table(table)
-    integrity.publish_output(stats, table)
+    """The AR filter, minimality and the output digest, as one stage span."""
+    with tracer.span("postprocess", cat=tracer.CAT_STAGE):
+        if use_ars:
+            rules = frequency.mine_association_rules(triples, min_support)
+            if stats is not None:
+                metrics.struct_set(stats, "association_rules", rules)
+            table = filter_ar_implied_cinds(table, rules)
+        if clean_implied:
+            table = minimality.minimize_table(table)
+        integrity.publish_output(stats, table)
     return table
 
 
@@ -524,9 +556,7 @@ def discover(triples, min_support: int, projections: str = "spo",
         return CindTable.empty()
     min_support = max(int(min_support), 1)
 
-    cap_n = segments.pow2_capacity(n)
-    padded = jnp.asarray(np.pad(triples, ((0, cap_n - n), (0, 0)),
-                                constant_values=np.iinfo(np.int32).max))
+    padded = upload_triples(triples)
     use_ars = use_association_rules and use_frequent_condition_filter
 
     if pair_backend in ("auto", "matmul"):

@@ -46,7 +46,7 @@ import numpy as np
 
 from .. import conditions as cc
 from ..data import NO_VALUE, CindTable
-from ..obs import datastats, integrity, metrics
+from ..obs import datastats, integrity, metrics, tracer
 from ..ops import cooc as cooc_ops
 from ..ops import frequency, minimality, pairs, segments, sketch
 from ..runtime import dispatch, faults
@@ -432,7 +432,10 @@ def _prepare_dense(padded, n, min_support, projections, use_fc_filter, use_ars,
         use_fc_filter=use_fc_filter, use_ars=use_ars)
     (line_gid, cap_id, cand_valid, n_lines_d, cap_code_d, cap_v1_d, cap_v2_d,
      num_caps_d) = prep
-    n_lines, num_caps = (int(x) for x in jax.device_get((n_lines_d, num_caps_d)))
+    # The first pull waits for the whole join-line build.
+    with tracer.span("pull-prepare-counts", cat=tracer.CAT_PULL):
+        n_lines, num_caps = (int(x) for x in
+                             jax.device_get((n_lines_d, num_caps_d)))
     if n_lines == 0 or num_caps == 0:
         return ()
     plan = cooc_ops.dense_plan(n_lines, num_caps)
@@ -443,35 +446,38 @@ def _prepare_dense(padded, n, min_support, projections, use_fc_filter, use_ars,
         line_gid, cap_id, cand_valid, jnp.int32(min_support),
         l_pad=l_pad, c_pad=c_pad, membership_dtype=plan.dtype)
     cooc_m = _stage_cooc_full(m)
-    (cap_code, cap_v1, cap_v2, dep_count, lens_h) = jax.device_get(
-        (cap_code_d[:num_caps], cap_v1_d[:num_caps], cap_v2_d[:num_caps],
-         jax.lax.slice(dep_count_d, (0,), (num_caps,)),
-         jax.lax.slice(lens, (0,), (n_lines,))))
+    with tracer.span("pull-capture-table", cat=tracer.CAT_PULL):
+        (cap_code, cap_v1, cap_v2, dep_count, lens_h) = jax.device_get(
+            (cap_code_d[:num_caps], cap_v1_d[:num_caps], cap_v2_d[:num_caps],
+             jax.lax.slice(dep_count_d, (0,), (num_caps,)),
+             jax.lax.slice(lens, (0,), (n_lines,))))
     if stats is not None:
-        lens64 = lens_h.astype(np.int64)
-        metrics.set_many(
-            stats, n_triples=n, n_lines=int((lens64 > 0).sum()),
-            n_frequent_rows=int(lens64.sum()),
-            n_line_rows=int(dep_count.astype(np.int64).sum()),
-            n_captures=num_caps, total_pairs=0,
-            max_line=int(lens64.max()) if lens64.size else 0,
-            pair_backend="matmul",
-            dense_plan=plan.describe(), cooc_dtype=plan.dtype,
-            plane_bits=plan.plane_bits)
-        metrics.struct_set(stats, "kernel_resolution",
-                           cooc_ops.resolution_report())
-        if datastats.enabled():
-            datastats.publish_line_stats(
-                stats, hist=datastats.log2_bucket_counts(lens64),
-                n_lines=int((lens64 > 0).sum()),
+        # Host reductions over the per-line vector, while the device idles.
+        with tracer.span("line-stats", cat=tracer.CAT_STAGE):
+            lens64 = lens_h.astype(np.int64)
+            metrics.set_many(
+                stats, n_triples=n, n_lines=int((lens64 > 0).sum()),
+                n_frequent_rows=int(lens64.sum()),
+                n_line_rows=int(dep_count.astype(np.int64).sum()),
+                n_captures=num_caps, total_pairs=0,
                 max_line=int(lens64.max()) if lens64.size else 0,
-                source="single")
-            sup = dep_count.astype(np.int64)
-            datastats.publish_capture_spectrum(
-                stats, hist=datastats.log2_bucket_counts(sup),
-                n_captures=num_caps,
-                max_support=int(sup.max()) if sup.size else 0,
-                source="single")
+                pair_backend="matmul",
+                dense_plan=plan.describe(), cooc_dtype=plan.dtype,
+                plane_bits=plan.plane_bits)
+            metrics.struct_set(stats, "kernel_resolution",
+                               cooc_ops.resolution_report())
+            if datastats.enabled():
+                datastats.publish_line_stats(
+                    stats, hist=datastats.log2_bucket_counts(lens64),
+                    n_lines=int((lens64 > 0).sum()),
+                    max_line=int(lens64.max()) if lens64.size else 0,
+                    source="single")
+                sup = dep_count.astype(np.int64)
+                datastats.publish_capture_spectrum(
+                    stats, hist=datastats.log2_bucket_counts(sup),
+                    n_captures=num_caps,
+                    max_support=int(sup.max()) if sup.size else 0,
+                    source="single")
     fn = _DenseCooc(m, cooc_m, dep_count_d, c_pad, n_lines, num_caps)
     return (fn, cap_code.astype(np.int64), cap_v1.astype(np.int64),
             cap_v2.astype(np.int64), dep_count.astype(np.int64), num_caps)
@@ -592,9 +598,9 @@ def _union_line_counts(m_mat, union_mask):
 
 
 def _run_lattice_dense(dc, cap_code, cap_v1, cap_v2, dep_count, num_caps,
-                       min_support, use_ars, rules, clean_implied,
-                       stats) -> CindTable:
-    """S2L lattice walk on the resident cooc matrix (dense backend)."""
+                       min_support, use_ars, rules, stats) -> CindTable:
+    """S2L lattice walk on the resident cooc matrix (dense backend); the
+    caller removes implied CINDs."""
     c_pad = dc.c_pad
     n_lines = dc.n_lines
     cooc_m = dc.cooc
@@ -628,18 +634,20 @@ def _run_lattice_dense(dc, cap_code, cap_v1, cap_v2, dep_count, num_caps,
         backends stay comparable."""
         if stats is None:
             return ()  # extras feed stats only; skip the pull entirely
-        flat = jax.device_get([x for _, u, nc in pending
-                               for x in (u,) + ((nc,) if nc is not None
-                                                else ())] + list(extras))
+        with tracer.span("pull-lattice-stats", cat=tracer.CAT_PULL):
+            flat = jax.device_get([x for _, u, nc in pending
+                                   for x in (u,) + ((nc,) if nc is not None
+                                                    else ())] + list(extras))
         it = iter(flat)
-        for key, _, nc in pending:
-            u = np.asarray(next(it), np.int64)[:n_lines]
-            n_cand = None if nc is None else int(next(it))
-            if n_cand is not None and n_cand == 0:
-                continue
-            n_pairs = int((u * (u - 1)).sum())
-            metrics.gauge_set(stats, key, n_pairs)
-            metrics.counter_add(stats, "total_pairs", n_pairs)
+        with tracer.span("lattice-stats", cat=tracer.CAT_STAGE):
+            for key, _, nc in pending:
+                u = np.asarray(next(it), np.int64)[:n_lines]
+                n_cand = None if nc is None else int(next(it))
+                if n_cand is not None and n_cand == 0:
+                    continue
+                n_pairs = int((u * (u - 1)).sum())
+                metrics.gauge_set(stats, key, n_pairs)
+                metrics.counter_add(stats, "total_pairs", n_pairs)
         return tuple(it)
 
     # --- 1/1.
@@ -681,8 +689,6 @@ def _run_lattice_dense(dc, cap_code, cap_v1, cap_v2, dep_count, num_caps,
                              n_proper_overlaps=int(extras[0]),
                              n_cinds_12=0, n_cinds_21=0, n_inferred_21=0,
                              n_cinds_22=0)
-        if clean_implied:
-            table = minimality.minimize_table(table)
         return table
     b_pad = segments.pow2_capacity(nb)
     s1_h = _lookup_capture_ids(
@@ -759,13 +765,10 @@ def _run_lattice_dense(dc, cap_code, cap_v1, cap_v2, dep_count, num_caps,
     all_d = np.concatenate([cind11_d, d12, d21, d22])
     all_r = np.concatenate([cind11_r, r12, r21, r22])
     all_s = dep_count[all_d]
-    table = CindTable(
+    return CindTable(
         dep_code=cap_code[all_d], dep_v1=cap_v1[all_d], dep_v2=cap_v2[all_d],
         ref_code=cap_code[all_r], ref_v1=cap_v1[all_r], ref_v2=cap_v2[all_r],
         support=all_s)
-    if clean_implied:
-        table = minimality.minimize_table(table)
-    return table
 
 
 # ---------------------------------------------------------------------------
@@ -960,9 +963,7 @@ def discover(triples, min_support: int, projections: str = "spo",
         # after candidate prep, so a fallback to chunked pays emission +
         # interning twice.  Pass pair_backend="chunked" when the data is known
         # to exceed the budget.
-        cap_n = segments.pow2_capacity(n)
-        padded = jnp.asarray(np.pad(triples, ((0, cap_n - n), (0, 0)),
-                                    constant_values=np.iinfo(np.int32).max))
+        padded = allatonce.upload_triples(triples)
         dense = _prepare_dense(padded, n, min_support, projections,
                                use_frequent_condition_filter, use_ars, stats)
         if dense == ():
@@ -979,8 +980,11 @@ def discover(triples, min_support: int, projections: str = "spo",
             metrics.struct_set(stats, "association_rules", rules)
         table = _run_lattice_dense(dc, cap_code, cap_v1, cap_v2, dep_count,
                                    num_caps, min_support, use_ars, rules,
-                                   clean_implied, stats)
-        integrity.publish_output(stats, table)
+                                   stats)
+        with tracer.span("postprocess", cat=tracer.CAT_STAGE):
+            if clean_implied:
+                table = minimality.minimize_table(table)
+            integrity.publish_output(stats, table)
         return table
     # --- Chunked backend: shared phase A (join lines + capture table + filter).
     st = allatonce.prepare_join_lines(triples, min_support, projections,

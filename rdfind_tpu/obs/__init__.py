@@ -25,6 +25,9 @@ surface they all publish through:
   flightrec crash-surviving bounded ring of the last N events, dumped
             atomically on signals, fault-ladder rungs and preemptions — the
             post-mortem when the jsonl tracer was off.
+  compiles  a per-program compile counter (program, seconds, persistent
+            cache hit or miss, open stage) kept from jax.monitoring events
+            and reported in RunResult.counters.
   sentinel  the BENCH_HISTORY.jsonl perf series + the noise-aware
             regression gate (``python -m rdfind_tpu.obs.sentinel --check``).
 
@@ -35,8 +38,8 @@ runtime/faults.py can depend on obs without widening their import footprint.
 
 from __future__ import annotations
 
-from . import (console, datastats, flightrec, forecast,  # noqa: F401
-               heartbeat, memory, metrics, report, sentinel, tracer)
+from . import (compiles, console, datastats, flightrec,  # noqa: F401
+               forecast, heartbeat, memory, metrics, report, sentinel, tracer)
 
 
 def active() -> bool:

@@ -1,6 +1,7 @@
 """Hierarchical host-span tracing, recorded as per-host JSONL event files.
 
-Span model: run -> stage -> pass -> dispatch / pull / exchange / checkpoint.
+Span model: run -> stage -> pass -> dispatch / pull / exchange / checkpoint;
+each compile is an instant (obs/compiles.py) inside whichever span is open.
 Every span is written as a Chrome-trace B/E event pair the moment it opens
 and closes (never buffered until run end), so a wedged run's trace still
 shows exactly which span it died inside.  ``report.export_chrome_trace``
@@ -11,9 +12,18 @@ Timestamps are epoch microseconds (time.time_ns) — the only clock multiple
 hosts share — and the merge tool rebases them to the earliest event.
 
 Host/device alignment: when tracing is enabled each span also enters a
-``jax.profiler.TraceAnnotation`` of the same name, so an XLA profiler trace
-(--profile-dir) carries the host span names on its TraceMe timeline and the
-two traces line up.  jax is imported lazily and only when tracing is ON.
+``jax.profiler.TraceAnnotation`` of the same name, so any JAX profiler
+trace running at the time carries the host span names on its TraceMe
+timeline and the two traces line up: the one that --profile-dir starts, or
+one that a caller starts around the run itself, as the benchmark harness
+does with --trace (benchmark/run.py).  jax is imported lazily and only when
+tracing is ON.
+
+Device side: the jitted stages wrap their sub-phases in ``jax.named_scope``
+under the fixed names below, so every HLO op carries the scope in its
+``op_name`` metadata and a profiler trace can sum device time by scope
+(benchmark/scopes.py).  A scope is trace-time metadata only: no op, no
+host work.
 
 Disabled cost: ``span()`` returns a shared no-op context manager after one
 module-global check — the hot path pays a function call and a branch, which
@@ -41,8 +51,20 @@ CAT_DISPATCH = "dispatch"
 CAT_PULL = "pull"
 CAT_EXCHANGE = "exchange"
 CAT_CHECKPOINT = "checkpoint"
+CAT_COMPILE = "compile"
 
 EVENTS_PREFIX = "events-host"
+
+# Device scopes of the join-line build, in program order: frequent
+# conditions, candidate emission, capture interning, line ids.  Renaming
+# one renames what benchmark/scopes.py and its metrics read.
+DEVICE_SCOPES = ("frequent_conditions", "emit_candidates", "intern_captures",
+                 "line_ids")
+(SCOPE_FREQUENT_CONDITIONS, SCOPE_EMIT_CANDIDATES, SCOPE_INTERN_CAPTURES,
+ SCOPE_LINE_IDS) = DEVICE_SCOPES
+# The pair phase's membership build and the minimality join.
+SCOPE_MEMBERSHIP = "membership"
+SCOPE_MINIMALITY = "minimality"
 
 
 class _NullSpan:

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import conditions as cc
+from ..obs import tracer
 from ..runtime import dispatch
 from . import segments
 
@@ -646,7 +647,8 @@ def _flush_pulls(pend):
 
     pend: list of (key, count, (d_dev, r_dev)).  Returns [(key, d, r)] with
     host int64 arrays truncated to their exact counts."""
-    flat = iter(jax.device_get([x for _, _, dr in pend for x in dr]))
+    with tracer.span("pull-decode-ids", cat=tracer.CAT_PULL):
+        flat = iter(jax.device_get([x for _, _, dr in pend for x in dr]))
     out = []
     for key, c, _ in pend:
         d, r = next(flat), next(flat)
@@ -738,7 +740,8 @@ def extract_packed_iter(thunks, tile_bits: int):
         return group, counts
 
     def drain_batch(group, counts):
-        counts = jax.device_get(counts)
+        with tracer.span("pull-decode-counts", cat=tracer.CAT_PULL):
+            counts = jax.device_get(counts)
         pend, pend_bytes = [], 0
 
         def drain():
@@ -832,7 +835,9 @@ def discover_pairs_dense(m, dep_count, cap_code, cap_v1, cap_v2, min_support,
     block_counts = None
     if block_skip_enabled() and l_pad % kl == 0 and c_pad % tile == 0 \
             and (n_line_blocks > 1 or len(los) > 1):
-        block_counts = np.asarray(_stage_block_counts(m, kl=kl, tile=tile))
+        with tracer.span("pull-block-counts", cat=tracer.CAT_PULL):
+            block_counts = np.asarray(_stage_block_counts(m, kl=kl,
+                                                          tile=tile))
     n_blocks_total = n_line_blocks * len(los)
     n_blocks_skipped = n_tiles_data_skipped = 0
     tile_blocks = {}

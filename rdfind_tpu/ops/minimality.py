@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from .. import conditions as cc
 from ..data import NO_VALUE, CindTable
+from ..obs import tracer
 from . import hashing, segments
 
 _N_SEG = 6  # key segments per side (see _implying_keys/_query_keys)
@@ -121,16 +122,17 @@ def _keep_from_found(found, fam, valid, n):
 def _stage_keep_mask(dep_code, dep_v1, dep_v2, ref_code, ref_v1, ref_v2,
                      n_valid):
     """Single-device keep mask over pow2-padded columns."""
-    n = dep_code.shape[0]
-    valid = jnp.arange(n, dtype=jnp.int32) < n_valid
-    cols = (dep_code, dep_v1, dep_v2, ref_code, ref_v1, ref_v2)
-    fam = _families(dep_code, ref_code, valid)
-    imp_cols, imp_valid = _implying_keys(cols, fam)
-    qry_cols, qry_valid = _query_keys(cols, fam)
-    tab_cols, _, _, n_tab = segments.masked_unique(imp_cols, imp_valid)
-    found = segments.masked_table_index(tab_cols, n_tab, qry_cols,
-                                        qry_valid) >= 0
-    return _keep_from_found(found, fam, valid, n)
+    with jax.named_scope(tracer.SCOPE_MINIMALITY):
+        n = dep_code.shape[0]
+        valid = jnp.arange(n, dtype=jnp.int32) < n_valid
+        cols = (dep_code, dep_v1, dep_v2, ref_code, ref_v1, ref_v2)
+        fam = _families(dep_code, ref_code, valid)
+        imp_cols, imp_valid = _implying_keys(cols, fam)
+        qry_cols, qry_valid = _query_keys(cols, fam)
+        tab_cols, _, _, n_tab = segments.masked_unique(imp_cols, imp_valid)
+        found = segments.masked_table_index(tab_cols, n_tab, qry_cols,
+                                            qry_valid) >= 0
+        return _keep_from_found(found, fam, valid, n)
 
 
 def _pad_cols(table: CindTable):

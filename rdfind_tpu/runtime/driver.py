@@ -24,7 +24,8 @@ from ..dictionary import Dictionary, intern_triples
 from ..io import native, ntriples, prefixes, reader
 from ..models import allatonce, approximate, late_bb, sharded, small_to_large
 from ..obs import memory as obs_memory
-from ..obs import console, flightrec, integrity, metrics, report, tracer
+from ..obs import (compiles, console, flightrec, integrity, metrics, report,
+                   tracer)
 from ..parallel.mesh import make_mesh
 from . import checkpoint, serving
 
@@ -761,6 +762,8 @@ def _obs_session(cfg: Config):
     console_port = (cfg.console_port if cfg.console_port is not None
                     else console.env_port())
     obs_memory.reset()
+    compiles.install()
+    compiles.reset()  # one run, one compile record
     flightrec.configure()  # re-read RDFIND_FLIGHTREC at every run start
     flightrec.reset()  # one run, one ring (dumps are per-incident anyway)
     if metrics_file:
@@ -1227,6 +1230,7 @@ def _emit_sinks(cfg: Config, phases: _Phases, counters: dict, table,
 
 def _report(cfg: Config, counters: dict, timings: dict) -> None:
     """Post-run statistics, incl. the CSV line (AbstractFlinkProgram.java:149-182)."""
+    counters.update(compiles.counters())
     try:
         import resource
         counters["peak-rss-mb"] = (

@@ -1,0 +1,156 @@
+"""Tracing inside the discover stage: the jitted stages' device scopes,
+the program-kept compile counter, and the host spans around the dense
+paths' pulls."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from rdfind_tpu.data import CindTable
+from rdfind_tpu.models import allatonce
+from rdfind_tpu.obs import report, tracer
+from rdfind_tpu.ops import minimality
+from rdfind_tpu.runtime import driver
+from rdfind_tpu.utils import synth
+
+N = 64  # padded rows of every stage below
+
+
+def _i32(n=N, fill=0):
+    return jnp.full(n, fill, jnp.int32)
+
+
+def _lower_prepare():
+    return allatonce._stage_prepare.lower(
+        jnp.zeros((N, 3), jnp.int32), jnp.int32(N), jnp.int32(2),
+        projections="spo", use_fc_filter=True)
+
+
+def _lower_candidates():
+    return allatonce._stage_candidates.lower(
+        jnp.zeros((N, 3), jnp.int32), jnp.int32(N), jnp.int32(2),
+        projections="spo", use_fc_filter=True)
+
+
+def _lower_membership():
+    return allatonce._stage_membership.lower(
+        _i32(), _i32(), jnp.ones(N, bool), jnp.int32(2), l_pad=128,
+        c_pad=128, membership_dtype=None)
+
+
+def _lower_keep_mask():
+    return minimality._stage_keep_mask.lower(*[_i32() for _ in range(6)],
+                                             jnp.int32(N))
+
+
+STAGES = {
+    "_stage_prepare": (_lower_prepare, tracer.DEVICE_SCOPES),
+    "_stage_candidates": (_lower_candidates, tracer.DEVICE_SCOPES),
+    "_stage_membership": (_lower_membership, (tracer.SCOPE_MEMBERSHIP,)),
+    "_stage_keep_mask": (_lower_keep_mask, (tracer.SCOPE_MINIMALITY,)),
+}
+
+
+@pytest.mark.parametrize("stage", sorted(STAGES))
+def test_stage_ops_carry_their_scopes(stage):
+    """Every scope name reaches the compiled module's op_name metadata,
+    which is what a profiler trace reports per op."""
+    lower, names = STAGES[stage]
+    text = lower().compile().as_text()
+    paths = {p for line in text.splitlines() if 'op_name="' in line
+             for p in [line.split('op_name="', 1)[1].split('"', 1)[0]]}
+    parts = {part for p in paths for part in p.split("/")}
+    for name in names:
+        assert name in parts, (stage, name)
+
+
+def _write_planted(tmp_path, n=400):
+    triples = synth.inject_cind_structure(
+        synth.generate_triples(n, seed=3, n_predicates=8, n_entities=40),
+        n_rules=4, ref_size=12, dep_size=8)
+    path = str(tmp_path / "planted.nt")
+    synth.write_nt(path, triples)
+    return path
+
+
+def test_compile_counter_names_each_compile(tmp_path, monkeypatch):
+    """A fresh jit inside a driver run adds one named compile to the run's
+    counters, under the stage it compiled in; a second run of the same
+    shape compiles it no more."""
+
+    def compile_probe(x):
+        return x * 3 + 1
+
+    fresh = jax.jit(compile_probe)
+
+    def strategy(ids, min_support, **_):
+        jax.block_until_ready(fresh(jnp.asarray(ids)))
+        return CindTable.empty()
+
+    monkeypatch.setitem(driver.STRATEGIES, 0, strategy)
+    cfg = driver.Config(input_paths=[_write_planted(tmp_path)],
+                        min_support=2, traversal_strategy=0)
+    first = driver.run(cfg).counters
+    mine = [p for p in first["compile-programs"]
+            if p.startswith("jit(compile_probe)@")]
+    assert len(mine) == 1, first["compile-programs"]
+    program, seconds, verdict = mine[0].rsplit(":", 2)
+    assert program == "jit(compile_probe)@discover"
+    assert float(seconds) > 0
+    assert verdict == "uncached"  # the tests keep the persistent cache off
+    assert first["compiles"] == len(first["compile-programs"]) >= 1
+    assert first["compile-misses"] >= 1
+    assert first["compile-s"] >= float(seconds)
+
+    second = driver.run(cfg).counters
+    assert not any(p.startswith("jit(compile_probe)@")
+                   for p in second["compile-programs"])
+
+
+@pytest.mark.parametrize("strategy,pulls", [
+    (0, {"pull-prepare-counts", "pull-cind-count", "pull-cind-ids"}),
+    (1, {"pull-prepare-counts", "pull-capture-table", "pull-lattice-stats"}),
+])
+def test_discover_pull_spans(tmp_path, monkeypatch, strategy, pulls):
+    """A traced run's pulls and postprocessing have spans of their own
+    under the discover stage, with no span closed out of order; each
+    compile is an instant on the trace."""
+    mismatched = []
+    close = tracer.Tracer.close
+
+    def recording_close(self):
+        mismatched.append(self.n_mismatched)
+        close(self)
+
+    monkeypatch.setattr(tracer.Tracer, "close", recording_close)
+    trace_dir = str(tmp_path / "trace")
+    cfg = driver.Config(input_paths=[_write_planted(tmp_path)],
+                        min_support=2, traversal_strategy=strategy,
+                        clean_implied=True, trace_dir=trace_dir)
+    res = driver.run(cfg)
+    assert len(res.table) > 0
+    assert mismatched == [0]
+
+    events = report.load_events(os.path.join(trace_dir, "events-host0.jsonl"))
+    roots, unclosed = report.build_span_tree(
+        [e for e in events if e["ph"] in "BEi"])
+    assert unclosed == []
+    discover = [n for n, _ in report.walk_spans(roots)
+                if n["name"] == "discover" and n["cat"] == tracer.CAT_STAGE]
+    assert len(discover) == 1
+    below = [n for n, _ in report.walk_spans(discover[0]["children"])]
+    pull_names = {n["name"] for n in below if n["cat"] == tracer.CAT_PULL}
+    assert pulls <= pull_names, pull_names
+    assert all(name.startswith("pull-") for name in pull_names)
+    assert any(n["name"] == "postprocess" and n["cat"] == tracer.CAT_STAGE
+               for n in below)
+    compiled = [e for e in events if e["name"] == "compile"]
+    assert len(compiled) == res.counters["compiles"]
+    for e in compiled:
+        assert e["cat"] == tracer.CAT_COMPILE
+        assert {"program", "seconds", "cache", "stage"} <= set(e["args"])
+    assert np.isclose(sum(e["args"]["seconds"] for e in compiled),
+                      res.counters["compile-s"], atol=1e-3)
