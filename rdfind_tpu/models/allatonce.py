@@ -38,8 +38,7 @@ import numpy as np
 from .. import conditions as cc
 from ..data import CindTable
 from ..obs import datastats, integrity, metrics, tracer
-from ..ops import cooc, frequency, minimality, pairs, segments
-from ..ops.emission import emit_join_candidates
+from ..ops import cooc, emission, frequency, minimality, pairs, segments
 
 SENTINEL = segments.SENTINEL
 
@@ -67,15 +66,26 @@ def upload_triples(triples: np.ndarray):
 
 def _emit_candidates(triples, n_valid, min_support, projections, use_fc_filter,
                      use_ars):
-    """The frequent-condition prefilter and candidate emission shared by
-    _stage_candidates and _stage_prepare, each under its device scope."""
+    """The frequent-condition prefilter, candidate emission and capture
+    interning shared by _stage_candidates and _stage_prepare, each under its
+    device scope.  Returns (cands, (cap_id, num_caps, cap_code, cap_v1,
+    cap_v2)).
+
+    With the filter off the condition sorts still run, at support 1, where
+    every valid row passes as with frequency.no_filter: interning reads its
+    ranks off them on either path and never sorts the candidate rows.
+    """
     with jax.named_scope(tracer.SCOPE_FREQUENT_CONDITIONS):
         valid_t = jnp.arange(triples.shape[0], dtype=jnp.int32) < n_valid
-        freq = (frequency.triple_frequencies(triples, valid_t, min_support,
-                                             find_ar_implied=use_ars)
-                if use_fc_filter else frequency.no_filter(valid_t))
+        freq = frequency.triple_frequencies(
+            triples, valid_t, min_support if use_fc_filter else 1,
+            find_ar_implied=use_ars and use_fc_filter)
     with jax.named_scope(tracer.SCOPE_EMIT_CANDIDATES):
-        return emit_join_candidates(triples, freq, projections)
+        cands = emission.emit_join_candidates(triples, freq, projections)
+    # Capture table in canonical (code, v1, v2) order, matching the
+    # reference's Condition.compare.
+    with jax.named_scope(tracer.SCOPE_INTERN_CAPTURES):
+        return cands, emission.intern_captures(freq, projections)
 
 
 @functools.partial(jax.jit,
@@ -87,14 +97,8 @@ def _stage_candidates(triples, n_valid, min_support, *, projections, use_fc_filt
     Returns (line_val, line_cap, n_rows, cap_code, cap_v1, cap_v2, num_caps); all
     arrays have capacity 3*|projections|*N with valid data compacted to the front.
     """
-    cands = _emit_candidates(triples, n_valid, min_support, projections,
-                             use_fc_filter, use_ars)
-
-    # Intern captures: (code, v1, v2) -> dense capture id; table in canonical
-    # (code, v1, v2) sorted order, matching the reference's Condition.compare.
-    with jax.named_scope(tracer.SCOPE_INTERN_CAPTURES):
-        (cap_cols, _, cap_id, num_caps) = segments.masked_unique(
-            [cands.code, cands.v1, cands.v2], cands.valid)
+    cands, (cap_id, num_caps, cap_code, cap_v1, cap_v2) = _emit_candidates(
+        triples, n_valid, min_support, projections, use_fc_filter, use_ars)
 
     # Join lines: distinct (join value, capture) occurrences, sorted by value.
     with jax.named_scope(tracer.SCOPE_LINE_IDS):
@@ -103,7 +107,7 @@ def _stage_candidates(triples, n_valid, min_support, *, projections, use_fc_filt
             [cands.join_val, cap_id_keyed], cands.valid)
 
     return (line_cols[0], line_cols[1], n_rows,
-            cap_cols[0], cap_cols[1], cap_cols[2], num_caps)
+            cap_code, cap_v1, cap_v2, num_caps)
 
 
 @jax.jit
@@ -128,7 +132,10 @@ def _stage_capture_filter(line_val, line_cap, n_rows, min_support):
 def _stage_prepare(triples, n_valid, min_support, *, projections, use_fc_filter,
                    use_ars=False):
     """Candidate emission + capture interning + dense line ids, for the dense
-    cooc path.  Minimal sort passes, no host row data.
+    cooc path.  No host row data, and the 3|projections|N candidate rows are
+    never sorted: capture ids come from the frequent-condition sorts, line
+    ids from one sort of the |projections|N join-value rows
+    (emission.intern_captures, emission.candidate_line_ids).
 
     Unlike the chunked pipeline, this deliberately skips BOTH the
     (value, capture) row dedupe (the membership scatter's .set(1) dedupes for
@@ -139,18 +146,17 @@ def _stage_prepare(triples, n_valid, min_support, *, projections, use_fc_filter,
     (_stage_membership).
 
     Returns (line_gid, cap_id, valid, n_lines, cap_code, cap_v1, cap_v2,
-    num_caps) at candidate-row capacity.
+    num_caps): line_gid of shape (|projections|, N), one line id per
+    projection and triple row (emission.candidate_line_ids), the rest at
+    candidate-row capacity.
     """
-    cands = _emit_candidates(triples, n_valid, min_support, projections,
-                             use_fc_filter, use_ars)
-    with jax.named_scope(tracer.SCOPE_INTERN_CAPTURES):
-        (cap_cols, _, cap_id, num_caps) = segments.masked_unique(
-            [cands.code, cands.v1, cands.v2], cands.valid)
+    cands, (cap_id, num_caps, cap_code, cap_v1, cap_v2) = _emit_candidates(
+        triples, n_valid, min_support, projections, use_fc_filter, use_ars)
     with jax.named_scope(tracer.SCOPE_LINE_IDS):
-        line_gid, n_lines = segments.masked_dense_ids(cands.join_val,
-                                                      cands.valid)
+        line_gid, n_lines = emission.candidate_line_ids(cands,
+                                                        triples.shape[0])
     return (line_gid, cap_id, cands.valid, n_lines,
-            cap_cols[0], cap_cols[1], cap_cols[2], num_caps)
+            cap_code, cap_v1, cap_v2, num_caps)
 
 
 @functools.partial(jax.jit,
@@ -158,6 +164,9 @@ def _stage_prepare(triples, n_valid, min_support, *, projections, use_fc_filter,
 def _stage_membership(line_gid, cap_id, valid, min_support, *, l_pad, c_pad,
                       membership_dtype):
     """Membership matrix + the aggregates that fall out of it.
+
+    line_gid is _stage_prepare's (|projections|, N) line ids; each candidate
+    row takes its own inside the membership scatter.
 
     `membership_dtype` (callers pass the dense plan's resolved dtype) is
     load-bearing: it
@@ -170,7 +179,8 @@ def _stage_membership(line_gid, cap_id, valid, min_support, *, l_pad, c_pad,
     matching the chunked path's per-line pair accounting.
     """
     with jax.named_scope(tracer.SCOPE_MEMBERSHIP):
-        m = cooc.build_membership(line_gid, cap_id, valid, l_pad=l_pad,
+        m = cooc.build_membership(emission.candidate_row_ids(line_gid),
+                                  cap_id, valid, l_pad=l_pad,
                                   c_pad=c_pad, dtype=membership_dtype)
         acc = jnp.int32 if m.dtype == jnp.int8 else jnp.float32
         dep_count = jnp.sum(m, axis=0, dtype=acc).astype(jnp.int32)
@@ -473,7 +483,9 @@ def _discover_dense(triples, padded, n, min_support, projections, use_fc_filter,
                 n_lines=int((lens_h > 0).sum()), n_captures=num_caps,
                 total_pairs=total_pairs,
                 max_line=int(lens_h.max()) if n_lines else 0,
-                pair_backend="matmul")
+                pair_backend="matmul",
+                join_sort_rows=emission.join_sort_rows(padded.shape[0],
+                                                       projections))
             if datastats.enabled():
                 datastats.publish_line_stats(
                     stats, hist=datastats.log2_bucket_counts(lens_h),

@@ -48,7 +48,7 @@ from .. import conditions as cc
 from ..data import NO_VALUE, CindTable
 from ..obs import datastats, integrity, metrics, tracer
 from ..ops import cooc as cooc_ops
-from ..ops import frequency, minimality, pairs, segments, sketch
+from ..ops import emission, frequency, minimality, pairs, segments, sketch
 from ..runtime import dispatch, faults
 from . import allatonce
 
@@ -462,6 +462,8 @@ def _prepare_dense(padded, n, min_support, projections, use_fc_filter, use_ars,
                 n_captures=num_caps, total_pairs=0,
                 max_line=int(lens64.max()) if lens64.size else 0,
                 pair_backend="matmul",
+                join_sort_rows=emission.join_sort_rows(padded.shape[0],
+                                                       projections),
                 dense_plan=plan.describe(), cooc_dtype=plan.dtype,
                 plane_bits=plan.plane_bits)
             metrics.struct_set(stats, "kernel_resolution",

@@ -49,28 +49,81 @@ class TripleFrequency:
     unary_ok: jnp.ndarray  # (N, 3) bool
     binary_ok: jnp.ndarray  # (N, 3) bool
     binary_ar_implied: jnp.ndarray  # (N, 3) bool
+    # The condition sorts behind the verdicts, one per condition type (the
+    # 3 fields, then _FIELD_PAIRS), for interning captures from them
+    # (emission.intern_captures); empty where the verdicts were not sorted.
+    sorts: tuple = ()
+
+
+@dataclasses.dataclass
+class ConditionSort:
+    """One condition type's sort of the triple rows, for interning the
+    captures of its capture-forming conditions (the keys whose verdicts
+    pass): per row, the rank of its condition among them in ascending key
+    order (garbage where its condition forms none); in key order, the key
+    columns and the first row of each of them."""
+
+    rank: jnp.ndarray  # (N,) int32, row order
+    keys: list  # 1 or 2 (N,) int32 columns, key order
+    first: jnp.ndarray  # (N,) bool, key order
+
+
+def _rank_and_unsort(runs, first):
+    """(row counts, ConditionSort) from a sort and its capture-forming first
+    rows: the counts and the ranks go back to row order in one unsort."""
+    counts, rank = segments.unsort(
+        runs.perm, [runs.counts, jnp.cumsum(first, dtype=jnp.int32) - 1])
+    return counts, ConditionSort(rank, runs.keys, first)
+
+
+def _binary_verdicts(cnt, cnt_a, cnt_b, min_support, find_ar_implied):
+    """(binary_ok, ar_implied) of field-pair conditions from their count and
+    their two fields' counts, in whatever row order those come."""
+    ok = cnt >= min_support
+    if not find_ar_implied:
+        return ok, jnp.zeros_like(ok)
+    # Rule (a -> b) or (b -> a) with confidence 1 over frequent conditions:
+    # emission then suppresses the redundant binary capture
+    # (CreateJoinPartners.scala:100-146 with the AR broadcast).
+    return ok, ((cnt == cnt_a) | (cnt == cnt_b)) & ok
 
 
 def triple_frequencies(triples, valid, min_support,
                        find_ar_implied: bool = False) -> TripleFrequency:
-    """Exact unary + binary condition frequencies, evaluated on the triples' own rows."""
-    unary_cnt = [segments.masked_row_counts([triples[:, f]], valid) for f in range(3)]
-    binary_cnt = [segments.masked_row_counts([triples[:, a], triples[:, b]], valid)
-                  for a, b in _FIELD_PAIRS]
+    """Exact unary + binary condition frequencies, evaluated on the triples' own rows.
+
+    At min_support 1 without association rules every valid row passes, as
+    with no_filter, and the sorts are still made for the capture interning.
+    Each pair sort carries its two fields' counts, so a pair condition's
+    verdicts are read in its own key order too.
+    """
+    unary_cnt, sorts = [], []
+    for f in range(3):
+        runs = segments.masked_sorted_runs([triples[:, f]], valid)
+        cnt, srt = _rank_and_unsort(
+            runs, runs.starts & (runs.counts >= min_support))
+        unary_cnt.append(cnt)
+        sorts.append(srt)
+    ok, ar = [], []
+    for a, b in _FIELD_PAIRS:
+        runs = segments.masked_sorted_runs(
+            [triples[:, a], triples[:, b]], valid,
+            payload=(unary_cnt[a], unary_cnt[b]))
+        cnt_a, cnt_b = runs.payload
+        s_ok, s_ar = _binary_verdicts(runs.counts, cnt_a, cnt_b, min_support,
+                                      find_ar_implied)
+        cnt, srt = _rank_and_unsort(
+            runs, runs.starts & s_ok & ~s_ar & (cnt_a >= min_support)
+            & (cnt_b >= min_support))
+        sorts.append(srt)
+        row_ok, row_ar = _binary_verdicts(cnt, unary_cnt[a], unary_cnt[b],
+                                          min_support, find_ar_implied)
+        ok.append(row_ok)
+        ar.append(row_ar)
     unary_ok = jnp.stack([c >= min_support for c in unary_cnt], axis=1)
-    binary_ok = jnp.stack([c >= min_support for c in binary_cnt], axis=1)
-    if find_ar_implied:
-        # Rule (a -> b) or (b -> a) with confidence 1 over frequent conditions:
-        # emission then suppresses the redundant binary capture
-        # (CreateJoinPartners.scala:100-146 with the AR broadcast).
-        ar = jnp.stack([
-            (binary_cnt[k] == unary_cnt[a]) | (binary_cnt[k] == unary_cnt[b])
-            for k, (a, b) in enumerate(_FIELD_PAIRS)
-        ], axis=1) & binary_ok
-    else:
-        ar = jnp.zeros_like(binary_ok)
-    return TripleFrequency(unary_ok=unary_ok, binary_ok=binary_ok,
-                           binary_ar_implied=ar)
+    return TripleFrequency(unary_ok=unary_ok, binary_ok=jnp.stack(ok, axis=1),
+                           binary_ar_implied=jnp.stack(ar, axis=1),
+                           sorts=tuple(sorts))
 
 
 def no_filter(valid) -> TripleFrequency:

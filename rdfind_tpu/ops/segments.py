@@ -8,6 +8,8 @@ shapes stay static per input size, and the sorts map onto XLA's TPU sort.
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax.lax
 import jax.numpy as jnp
 
@@ -46,20 +48,62 @@ def pow2_capacity(n: int) -> int:
     return 1 << max(0, int(n) - 1).bit_length() if n > 1 else 1
 
 
+def unsort(perm, cols):
+    """Columns given in the order `perm` sorted the rows, put back in row
+    order (out[perm[i]] = col[i]).  One sort by perm: on the TPU a sort that
+    carries its columns costs less than a scatter or a gather by a
+    permutation."""
+    return list(jax.lax.sort((perm, *cols), num_keys=1)[1:])
+
+
+def run_sums(starts, x):
+    """For each sorted row, the sum of int32 `x` >= 0 over its run, from
+    the run-start mask: three scans, no scatter or gather."""
+    n = x.shape[0]
+    if n == 0:
+        return x
+    total = jnp.cumsum(x, dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(starts, total - x, 0))
+    ends = jnp.concatenate([starts[1:], jnp.ones(1, bool)])
+    upto = jax.lax.cummin(jnp.where(ends, total, total[-1]), reverse=True)
+    return upto - before
+
+
+@dataclasses.dataclass
+class SortedRuns:
+    """One sort of masked key columns (masked_sorted_runs), all in key order."""
+
+    perm: jnp.ndarray  # (N,) int32 rows in ascending key order, invalid last
+    keys: list  # the masked key columns
+    starts: jnp.ndarray  # (N,) bool first row of each valid key's run
+    counts: jnp.ndarray  # (N,) int32 valid rows of each row's key (0 if invalid)
+    payload: list  # the payload columns
+
+
+def masked_sorted_runs(cols, valid, payload=()):
+    """One sort of the masked key columns that carries the validity, the
+    row index and `payload`, and the run counts read off it by scans: no
+    gather or scatter by the permutation (unsort puts results back in row
+    order)."""
+    n = cols[0].shape[0]
+    k = len(cols)
+    keys = [jnp.where(valid, c, SENTINEL) for c in cols]
+    out = jax.lax.sort((*keys, valid, *payload,
+                        jnp.arange(n, dtype=jnp.int32)), num_keys=k)
+    sorted_keys, v_sorted = list(out[:k]), out[k]
+    runs = run_starts(sorted_keys)
+    v_int = v_sorted.astype(jnp.int32)
+    return SortedRuns(out[-1], sorted_keys, runs & v_sorted,
+                      run_sums(runs, v_int) * v_int, list(out[k + 1:-1]))
+
+
 def masked_row_counts(cols, valid):
     """For each row, how many valid rows share its key.  Fixed-shape, jittable.
 
     Invalid rows get count 0.
     """
-    n = cols[0].shape[0]
-    cols = [jnp.where(valid, c, SENTINEL) for c in cols]
-    perm = lexsort(cols)
-    sorted_cols = [c[perm] for c in cols]
-    v_sorted = valid[perm].astype(jnp.int32)
-    gid = jnp.cumsum(run_starts(sorted_cols)).astype(jnp.int32) - 1
-    counts = jax.ops.segment_sum(v_sorted, gid, num_segments=n)
-    per_row_sorted = counts[gid] * v_sorted
-    return jnp.zeros(n, jnp.int32).at[perm].set(per_row_sorted)
+    runs = masked_sorted_runs(cols, valid)
+    return unsort(runs.perm, [runs.counts])[0]
 
 
 def masked_weighted_row_counts(cols, weights, valid):
@@ -143,23 +187,25 @@ def masked_dense_ids(col, valid):
     """Dense ids (0..n_ids-1, in ascending key order) for one key column.
 
     The light sibling of masked_unique for callers that need only the inverse
-    mapping and the count — skips the compaction argsort and the unique-row
-    columns (one sort pass total).  Invalid rows get a garbage id; mask them.
+    mapping and the count: one sort by key that carries the validity and
+    the row index, and one back to row order (unsort).  Invalid rows get a
+    garbage id; mask them.
     """
     n = col.shape[0]
     key = jnp.where(valid, col, SENTINEL)
-    perm = jnp.argsort(key)
-    v_sorted = valid[perm]
-    is_new = run_starts([key[perm]]) & v_sorted
-    gid = jnp.cumsum(is_new).astype(jnp.int32) - 1
-    inverse = jnp.zeros(n, jnp.int32).at[perm].set(gid)
-    return inverse, is_new.sum().astype(jnp.int32)
+    key_s, v_sorted, perm = jax.lax.sort(
+        (key, valid, jnp.arange(n, dtype=jnp.int32)), num_keys=1)
+    is_new = run_starts([key_s]) & v_sorted
+    gid = jnp.cumsum(is_new, dtype=jnp.int32) - 1
+    (inverse,) = unsort(perm, [gid])
+    return inverse, is_new.sum(dtype=jnp.int32)
 
 
 def compact(cols, keep):
     """Move rows with keep=True to the front (preserving order).  Jittable.
 
-    Returns (out_cols, n_kept).
+    Returns (out_cols, n_kept).  One stable sort on ~keep that carries the
+    columns, where a gather by its permutation would cost more on the TPU.
     """
-    order = jnp.argsort(~keep, stable=True)
-    return [c[order] for c in cols], keep.sum().astype(jnp.int32)
+    out = jax.lax.sort((~keep, *cols), num_keys=1, is_stable=True)
+    return list(out[1:]), keep.sum().astype(jnp.int32)

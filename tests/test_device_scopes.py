@@ -3,6 +3,7 @@ the program-kept compile counter, and the host spans around the dense
 paths' pulls."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -37,8 +38,8 @@ def _lower_candidates():
 
 def _lower_membership():
     return allatonce._stage_membership.lower(
-        _i32(), _i32(), jnp.ones(N, bool), jnp.int32(2), l_pad=128,
-        c_pad=128, membership_dtype=None)
+        jnp.zeros((1, N), jnp.int32), _i32(3 * N), jnp.ones(3 * N, bool),
+        jnp.int32(2), l_pad=128, c_pad=128, membership_dtype=None)
 
 
 def _lower_keep_mask():
@@ -65,6 +66,34 @@ def test_stage_ops_carry_their_scopes(stage):
     parts = {part for p in paths for part in p.split("/")}
     for name in names:
         assert name in parts, (stage, name)
+
+
+def _sorted_rows_by_scope(text):
+    """{scope: the row counts of the sorts under it} in a compiled module."""
+    out = {}
+    for line in text.splitlines():
+        if " sort(" in line and 'op_name="' in line:
+            parts = line.split('op_name="', 1)[1].split('"', 1)[0].split("/")
+            rows = int(re.search(r"\[(\d+)\]", line.split(" sort(")[0])[1])
+            for name in tracer.DEVICE_SCOPES:
+                if name in parts:
+                    out.setdefault(name, []).append(rows)
+    return out
+
+
+@pytest.mark.parametrize("stage,line_sort_rows", [
+    ("_stage_prepare", 3 * N), ("_stage_candidates", 9 * N)])
+def test_join_line_build_never_sorts_candidate_rows_to_intern(stage,
+                                                              line_sort_rows):
+    """The condition sorts of the N triple rows read under
+    frequent_conditions; interning only moves their rows back by position;
+    line ids sort one join-value column per projection in _stage_prepare
+    (the (value, capture) dedupe of _stage_candidates sorts the 9N
+    candidate rows)."""
+    sorts = _sorted_rows_by_scope(STAGES[stage][0]().compile().as_text())
+    assert set(sorts[tracer.SCOPE_FREQUENT_CONDITIONS]) == {N}
+    assert set(sorts[tracer.SCOPE_INTERN_CAPTURES]) == {N}
+    assert max(sorts[tracer.SCOPE_LINE_IDS]) == line_sort_rows
 
 
 def _write_planted(tmp_path, n=400):
