@@ -1621,31 +1621,60 @@ int64_t rdf_ingest_stream_finish(Ingest* ing) {
   ing->stats.intern_ns += ns_since(t0);
 
   // S-way merge of the shard-sorted runs into the byte-sorted global order
-  // (shards are hash-disjoint, so no cross-shard duplicates).
+  // (shards are hash-disjoint, so no cross-shard duplicates).  The order is
+  // cut into ranges at pivots drawn from the largest shard; a range starts
+  // in every shard at the pivot's lower bound and at the sum of those
+  // bounds globally, so the ranges merge independently, in parallel.
   t0 = Clock::now();
   int64_t total = 0;
-  for (int s = 0; s < S; s++) total += shard_distinct[s].size();
+  int big = 0;
+  for (int s = 0; s < S; s++) {
+    total += shard_distinct[s].size();
+    if (shard_distinct[s].size() > shard_distinct[big].size()) big = s;
+  }
   if (total >= (1ll << 31) - 1) {
     ing->error = "dictionary exceeds int32 id space";
     return -1;
   }
-  ing->sorted_vals.reserve(total);
+  ing->sorted_vals.resize(total);
   std::vector<std::vector<int32_t>> shard_to_global(S);
-  std::vector<size_t> cursor(S, 0);
   for (int s = 0; s < S; s++)
     shard_to_global[s].resize(shard_distinct[s].size());
-  for (int64_t rank = 0; rank < total; rank++) {
-    int best = -1;
+  const int R = n_threads;
+  // lo[r * S + s]: range r's first position in shard s; range R ends them.
+  std::vector<size_t> lo((R + 1) * S);
+  std::vector<int64_t> first_rank(R + 1, 0);
+  for (int r = 0; r <= R; r++) {
     for (int s = 0; s < S; s++) {
-      if (cursor[s] >= shard_distinct[s].size()) continue;
-      if (best < 0 ||
-          shard_distinct[s][cursor[s]] < shard_distinct[best][cursor[best]])
-        best = s;
+      const auto& d = shard_distinct[s];
+      size_t at = d.size();
+      if (r == 0) {
+        at = 0;
+      } else if (r < R && !shard_distinct[big].empty()) {
+        std::string_view pivot =
+            shard_distinct[big][shard_distinct[big].size() * r / R];
+        at = std::lower_bound(d.begin(), d.end(), pivot) - d.begin();
+      }
+      lo[r * S + s] = at;
+      first_rank[r] += at;
     }
-    shard_to_global[best][cursor[best]] = static_cast<int32_t>(rank);
-    ing->sorted_vals.push_back(shard_distinct[best][cursor[best]]);
-    cursor[best]++;
   }
+  parallel_for(R, n_threads, [&](int64_t r) {
+    std::vector<size_t> cursor(lo.begin() + r * S, lo.begin() + (r + 1) * S);
+    const size_t* end = lo.data() + (r + 1) * S;
+    for (int64_t rank = first_rank[r]; rank < first_rank[r + 1]; rank++) {
+      int best = -1;
+      for (int s = 0; s < S; s++) {
+        if (cursor[s] >= end[s]) continue;
+        if (best < 0 ||
+            shard_distinct[s][cursor[s]] < shard_distinct[best][cursor[best]])
+          best = s;
+      }
+      shard_to_global[best][cursor[best]] = static_cast<int32_t>(rank);
+      ing->sorted_vals[rank] = shard_distinct[best][cursor[best]];
+      cursor[best]++;
+    }
+  });
   ing->stats.merge_ns += partition_ns + ns_since(t0);
 
   // Upgrade the per-thread tables from in-shard ranks to global ranks.

@@ -262,9 +262,33 @@ def _values_from_buffer(raw: bytes, offsets: np.ndarray):
     suffix of one value can splice with an invalid prefix of the next into a
     valid sequence (b"\\xc3" + b"\\xa9" == "é"), so a whole-blob decode can
     succeed while individual values are invalid.  Returns (values, lossless).
+
+    A blob that decodes whole is split without decoding each value: every
+    value is then valid exactly when it starts on a character, not on a
+    continuation byte (0b10xxxxxx), and its characters lie between its
+    byte offsets less the continuation bytes before them.
     """
     n_values = len(offsets) - 1
     values = np.empty(n_values, object)
+    try:
+        text = raw.decode("utf-8")
+    except UnicodeDecodeError:
+        text = None
+    if text is not None and n_values:
+        offsets = np.asarray(offsets, np.int64)
+        if len(text) == len(raw):
+            chars = offsets
+        else:
+            cont = (np.frombuffer(raw, np.uint8) & 0xC0) == 0x80
+            before = np.concatenate([[0], np.cumsum(cont, dtype=np.int64)])
+            chars = offsets - before[offsets]
+            starts = offsets[:-1]
+            if cont[starts[starts < len(raw)]].any():
+                chars = None
+        if chars is not None:
+            bounds = chars.tolist()
+            values[:] = [text[a:b] for a, b in zip(bounds[:-1], bounds[1:])]
+            return values, True
     lossless = True
     for i in range(n_values):
         chunk = raw[offsets[i]:offsets[i + 1]]

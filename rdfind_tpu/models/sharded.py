@@ -93,6 +93,7 @@ from ..parallel.mesh import (AXIS, allgather_host_values,
                              make_mesh, maybe_link_probe, shard_map,
                              topology_hosts)
 from ..runtime import dispatch, faults, watchdog
+from . import sharded_dense
 
 SENTINEL = segments.SENTINEL
 
@@ -876,6 +877,11 @@ def sharded_ha_bits() -> int:
     return max(32, segments.pow2_capacity(max(v, 1)))
 
 
+def _block_rows(n: int, num_dev: int) -> int:
+    """Rows of each device's block of an n-row triple table."""
+    return max(T_LOC_FLOOR, segments.pow2_capacity(-(-n // num_dev)))
+
+
 def _shard_triples(triples, num_dev, t_loc: int | None = None):
     """Contiguous per-device split, padded to a shared power-of-two block.
 
@@ -884,7 +890,7 @@ def _shard_triples(triples, num_dev, t_loc: int | None = None):
     """
     n = triples.shape[0]
     if t_loc is None:
-        t_loc = max(T_LOC_FLOOR, segments.pow2_capacity(-(-n // num_dev)))
+        t_loc = _block_rows(n, num_dev)
     padded = np.full((num_dev * t_loc, 3), np.iinfo(np.int32).max, np.int32)
     n_valid = np.zeros(num_dev, np.int32)
     for dev in range(num_dev):
@@ -2945,6 +2951,13 @@ def discover_sharded_s2l(triples, min_support: int, mesh=None,
     identical lattice logic as small_to_large.discover (shared code), with each
     level's verification running as a masked pair phase over the mesh.  Output
     is identical to small_to_large.discover with matching flags.
+
+    Where the frequent-condition filter is on and the membership fits the
+    dense plan, on a flat mesh of one host without per-pass checkpoints or
+    the half-approximate cut, every level is verified instead against one
+    membership matmul per device summed over the mesh
+    (models/sharded_dense.py), as small_to_large.discover's dense backend
+    does on one device.
     """
     from . import small_to_large
 
@@ -2956,6 +2969,18 @@ def discover_sharded_s2l(triples, min_support: int, mesh=None,
     if not any(ch in projections for ch in "spo"):
         return CindTable.empty()
     min_support = max(int(min_support), 1)
+
+    num_dev = mesh.devices.size
+    if (preshard is None and progress is None and combine
+            and hier_spec(num_dev) is None
+            and not sharded_half_approx_enabled()):
+        t_loc = _block_rows(triples.shape[0], num_dev)
+        if sharded_dense.eligible(num_dev, t_loc, min_support, use_fis):
+            table = _discover_s2l_dense(triples, t_loc, min_support, mesh,
+                                        projections, use_ars, clean_implied,
+                                        stats)
+            if table is not None:
+                return table
 
     try:
         pipe = _Pipeline(mesh, triples, min_support, projections, use_fis,
@@ -2994,6 +3019,38 @@ def discover_sharded_s2l(triples, min_support: int, mesh=None,
         return _single_device_fallback(
             "small_to_large", e, triples, preshard, min_support, projections,
             use_fis, use_ars, clean_implied, stats)
+
+
+def _discover_s2l_dense(triples, t_loc, min_support, mesh, projections,
+                        use_ars, clean_implied, stats):
+    """Sharded SmallToLarge on the dense path (models/sharded_dense.py), or
+    None when its membership matrix does not fit the dense plan."""
+    from . import small_to_large
+
+    padded, n_valid, _ = _shard_triples(triples, mesh.devices.size, t_loc)
+    with tracer.span("upload-triples", cat=tracer.CAT_DISPATCH):
+        g_triples = make_global(padded, mesh)
+        g_n_valid = make_global(n_valid, mesh)
+    dense = sharded_dense.prepare(mesh, g_triples, g_n_valid, t_loc,
+                                  int(triples.max()) + 1, min_support,
+                                  projections, use_ars, stats)
+    if dense is None:
+        return None
+    if dense == ():
+        return CindTable.empty()
+    cooc_fn, cap_code, cap_v1, cap_v2, dep_count = dense
+    if stats is not None:
+        metrics.set_many(stats, n_triples=triples.shape[0],
+                         n_captures=cap_code.shape[0], total_pairs=0)
+    rules = (_mine_rules(triples, None, min_support, mesh)
+             if use_ars else None)
+    if use_ars and stats is not None:
+        metrics.struct_set(stats, "association_rules", rules)
+    table = small_to_large._run_lattice(
+        cooc_fn, cap_code, cap_v1, cap_v2, dep_count, cap_code.shape[0],
+        min_support, use_ars, rules, clean_implied, stats, mesh=mesh)
+    _publish_output_digest(stats, table)
+    return table
 
 
 @functools.lru_cache(maxsize=None)

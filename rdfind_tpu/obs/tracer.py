@@ -65,6 +65,11 @@ DEVICE_SCOPES = ("frequent_conditions", "emit_candidates", "intern_captures",
 # The pair phase's membership build and the minimality join.
 SCOPE_MEMBERSHIP = "membership"
 SCOPE_MINIMALITY = "minimality"
+# The join-line exchange of the sharded stages (models/sharded_dense.py):
+# the packing of the send buffers and every collective (all_to_all,
+# all_gather, psum), read by the benchmark's exchange_s.  Never nested
+# inside one of the scopes above, so an op has one of them at most.
+SCOPE_EXCHANGE = "exchange"
 
 
 class _NullSpan:

@@ -88,6 +88,14 @@ def _binary_verdicts(cnt, cnt_a, cnt_b, min_support, find_ar_implied):
     return ok, ((cnt == cnt_a) | (cnt == cnt_b)) & ok
 
 
+def pair_forms_capture(cnt, cnt_a, cnt_b, min_support, find_ar_implied):
+    """Whether a field-pair condition forms a capture, from its count and
+    its two fields' counts: it and both fields are frequent, and no
+    association rule implies it."""
+    ok, ar = _binary_verdicts(cnt, cnt_a, cnt_b, min_support, find_ar_implied)
+    return ok & ~ar & (cnt_a >= min_support) & (cnt_b >= min_support)
+
+
 def triple_frequencies(triples, valid, min_support,
                        find_ar_implied: bool = False) -> TripleFrequency:
     """Exact unary + binary condition frequencies, evaluated on the triples' own rows.
@@ -110,11 +118,9 @@ def triple_frequencies(triples, valid, min_support,
             [triples[:, a], triples[:, b]], valid,
             payload=(unary_cnt[a], unary_cnt[b]))
         cnt_a, cnt_b = runs.payload
-        s_ok, s_ar = _binary_verdicts(runs.counts, cnt_a, cnt_b, min_support,
-                                      find_ar_implied)
         cnt, srt = _rank_and_unsort(
-            runs, runs.starts & s_ok & ~s_ar & (cnt_a >= min_support)
-            & (cnt_b >= min_support))
+            runs, runs.starts & pair_forms_capture(
+                runs.counts, cnt_a, cnt_b, min_support, find_ar_implied))
         sorts.append(srt)
         row_ok, row_ar = _binary_verdicts(cnt, unary_cnt[a], unary_cnt[b],
                                           min_support, find_ar_implied)

@@ -8,6 +8,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 # RDFIND_TEST_TPU=1 lifts the CPU pin so the `-m tpu` tier (on-chip Pallas
 # parity + end-to-end golden, tests/test_tpu_tier.py) can reach the real
 # backend; everything below down to the final config.update is gated on it.
@@ -69,3 +71,51 @@ os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO_ROOT not in sys.path:
     sys.path.insert(0, _REPO_ROOT)
+
+
+def _memory_maps() -> int:
+    """This process's memory mappings (0 where /proc is missing)."""
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            return sum(1 for _ in f)
+    except OSError:
+        return 0
+
+
+def _max_memory_maps() -> int:
+    try:
+        with open("/proc/sys/vm/max_map_count") as f:
+            return int(f.read())
+    except (OSError, ValueError):
+        return 65530  # the kernel's default
+
+
+_MAP_BUDGET = _max_memory_maps() // 2
+
+
+def pytest_runtest_teardown(item, nextitem):
+    """Every program a test compiles stays loaded in JAX's caches, and each
+    loaded XLA CPU executable holds memory mappings of its own (a few
+    hundred for an 8-device shard_map program).  A worker that has run a
+    few hundred such tests nears the kernel's per-process limit
+    (vm.max_map_count), and the next executable's load then fails inside
+    XLA, killing the worker: tests/test_sharded_half_approx.py alone leaves
+    ~59k of the default 65,530.  Past half the limit, drop the caches; the
+    next tests compile what they need again."""
+    if _memory_maps() > _MAP_BUDGET:
+        import gc
+
+        import jax
+
+        jax.clear_caches()
+        gc.collect()
+
+
+@pytest.fixture
+def s2l_pair_phase(monkeypatch):
+    """Sharded strategy 1 on its masked pair phase, for the tests of that
+    phase's machinery (the dense path of models/sharded_dense.py takes
+    the small graphs of these tests otherwise)."""
+    from rdfind_tpu.models import sharded_dense
+
+    monkeypatch.setattr(sharded_dense, "eligible", lambda *a, **k: False)

@@ -214,7 +214,8 @@ def test_single_device_silent_when_disabled():
 
 
 @pytest.mark.parametrize("strategy", sorted(STRATEGIES))
-def test_sharded_bit_identical_with_data_plane(mesh8, strategy, monkeypatch):
+def test_sharded_bit_identical_with_data_plane(mesh8, strategy, monkeypatch,
+                                              s2l_pair_phase):
     """The acceptance matrix: rows identical with datastats+forecast on vs
     off, and the on-run actually published the data-plane keys."""
     triples = _workload()

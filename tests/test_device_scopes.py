@@ -183,3 +183,71 @@ def test_discover_pull_spans(tmp_path, monkeypatch, strategy, pulls):
         assert {"program", "seconds", "cache", "stage"} <= set(e["args"])
     assert np.isclose(sum(e["args"]["seconds"] for e in compiled),
                       res.counters["compile-s"], atol=1e-3)
+
+
+def _op_paths(text, op_filter=lambda line: True):
+    return [line.split('op_name="', 1)[1].split('"', 1)[0].split("/")
+            for line in text.splitlines()
+            if 'op_name="' in line and op_filter(line)]
+
+
+def _sharded_programs():
+    """The sharded dense stages compiled on four CPU devices, tiny shapes."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from rdfind_tpu.models import sharded_dense
+    from rdfind_tpu.parallel.mesh import AXIS, make_mesh
+
+    mesh = make_mesh(4)
+
+    def arg(shape, spec):
+        return jax.ShapeDtypeStruct(shape, jnp.int32,
+                                    sharding=NamedSharding(mesh, spec))
+
+    emit = sharded_dense._emit_step.lower(
+        arg((4 * N, 3), P(AXIS, None)), arg((4,), P(AXIS)), arg((), P()),
+        mesh=mesh, projections="spo", use_ars=True,
+        offer_cap=sharded_dense.offer_capacity(N, 4, 2))
+    cooc = sharded_dense._cooc_step.lower(
+        arg((4 * N, 3), P(AXIS, None)), arg((9, 4 * N), P(None, AXIS)),
+        mesh=mesh, projections="spo", cap=2 * N, l_pad=256, c_pad=128,
+        dtype="bf16")
+    return {"_emit_step": emit.compile().as_text(),
+            "_cooc_step": cooc.compile().as_text()}
+
+
+_COLLECTIVES = (" all-to-all(", " all-gather(", " all-reduce(",
+                " all-gather-start(", " all-reduce-start(")
+
+
+def test_sharded_collectives_carry_exchange():
+    """The join-line exchange of the sharded dense stages, every collective
+    among it, runs under `exchange`; their other work reads under the names
+    the single-device build uses."""
+    names = set(tracer.DEVICE_SCOPES) | {
+        tracer.SCOPE_MEMBERSHIP, tracer.SCOPE_EXCHANGE}
+    want = {"_emit_step": {tracer.SCOPE_FREQUENT_CONDITIONS,
+                           tracer.SCOPE_INTERN_CAPTURES,
+                           tracer.SCOPE_EXCHANGE},
+            "_cooc_step": {tracer.SCOPE_MEMBERSHIP, tracer.SCOPE_EXCHANGE}}
+    for stage, text in _sharded_programs().items():
+        coll = _op_paths(text, lambda line: any(c in line
+                                                for c in _COLLECTIVES))
+        assert coll, stage
+        assert all(tracer.SCOPE_EXCHANGE in p for p in coll), stage
+        scoped = {n for p in _op_paths(text) for n in p if n in names}
+        assert scoped == want[stage], stage
+
+
+@pytest.mark.parametrize("stage,scopes", [
+    ("_stage_prepare", set(tracer.DEVICE_SCOPES)),
+    ("_stage_membership", {tracer.SCOPE_MEMBERSHIP}),
+])
+def test_single_device_stages_keep_their_scopes(stage, scopes):
+    """The one-chip join-line build and membership carry exactly the scopes
+    they had before the sharded stages had theirs: no `exchange`."""
+    text = STAGES[stage][0]().compile().as_text()
+    names = set(tracer.DEVICE_SCOPES) | {
+        tracer.SCOPE_MEMBERSHIP, tracer.SCOPE_MINIMALITY,
+        tracer.SCOPE_EXCHANGE}
+    assert {n for p in _op_paths(text) for n in p if n in names} == scopes

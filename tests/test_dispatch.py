@@ -59,7 +59,8 @@ def test_pipelined_matches_forced_sync(mesh8, monkeypatch):
     assert s_async["cap_p_final"] >= s_async["planned_caps"]["pairs"]
 
 
-def test_pipelined_s2l_matches_forced_sync(mesh8, monkeypatch):
+def test_pipelined_s2l_matches_forced_sync(mesh8, monkeypatch,
+                                          s2l_pair_phase):
     """The S2L lattice drives run_cooc once per level; every level's pass
     loop must stay exact under pipelining."""
     triples = _multipass_workload()

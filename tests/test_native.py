@@ -126,3 +126,24 @@ def test_boundary_spliced_invalid_utf8_parity(tmp_path):
     assert_same(got, want)
     # Each invalid value decoded independently (with U+FFFD), never conflated.
     assert len(set(got[1].values)) == len(got[1].values)
+
+
+@pytest.mark.parametrize("values", [
+    [b"abc", b"", b"x"],                     # ASCII, an empty value
+    ["héllo".encode(), b"ab", "€x".encode(), b""],  # multi-byte
+    [b"a\xc3", b"\xa9b", b"Z"],              # spliced: the blob is valid
+    [b"\xff", b"ok"],                        # the blob itself is invalid
+])
+def test_values_from_buffer_matches_per_value_decode(values):
+    """The dictionary blob's split equals a decode of each value alone, and
+    `lossless` says whether every value was valid UTF-8 on its own."""
+    offsets = np.cumsum([0] + [len(v) for v in values])
+    got, lossless = native._values_from_buffer(b"".join(values), offsets)
+    assert list(got) == [v.decode(errors="replace") for v in values]
+    valid = True
+    for v in values:
+        try:
+            v.decode("utf-8")
+        except UnicodeDecodeError:
+            valid = False
+    assert lossless == valid

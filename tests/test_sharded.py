@@ -159,7 +159,7 @@ def test_sharded_s2l_flags(mesh8, use_fis, use_ars):
     assert a.to_rows() == b.to_rows()
 
 
-def test_sharded_s2l_skew_split(mesh8):
+def test_sharded_s2l_skew_split(mesh8, s2l_pair_phase):
     """A hot join value must drive the S2L giant-line path and stay correct."""
     triples = generate_triples(150, seed=13, n_predicates=5, n_entities=16)
     hot = np.stack([np.arange(100, 160, dtype=np.int32),
@@ -410,7 +410,7 @@ def test_skew_policy_validation():
         sharded.SkewPolicy(strategy=3)
 
 
-def test_host_capture_budget_guard(mesh8, monkeypatch):
+def test_host_capture_budget_guard(mesh8, monkeypatch, s2l_pair_phase):
     """The host-side lattice pull fails loudly past its stated budget."""
     import os
     monkeypatch.setitem(os.environ, "RDFIND_HOST_CAPTURES_BUDGET", "4")
@@ -467,7 +467,7 @@ def test_join_histogram_sharded_matches_host(mesh8):
     assert got == want
 
 
-def test_sharded_multipass_pair_phase(mesh8, monkeypatch):
+def test_sharded_multipass_pair_phase(mesh8, monkeypatch, s2l_pair_phase):
     """A tiny pair-row budget must force dep-slice streaming passes (the
     bounded-memory pair phase) on BOTH strategies, with identical output."""
     triples = generate_triples(300, seed=21, n_predicates=8, n_entities=32)
