@@ -14,7 +14,6 @@ string join value).
 
 from __future__ import annotations
 
-import dataclasses
 import zlib
 
 import numpy as np
@@ -32,14 +31,20 @@ def value_shard(value, num_shards: int) -> int:
     return zlib.crc32(str(value).encode("utf-8")) % num_shards
 
 
-@dataclasses.dataclass
 class Dictionary:
     """Sorted unique values; id = rank in sorted order."""
 
-    values: np.ndarray  # sorted 1-D array of str/bytes
+    def __init__(self, values: np.ndarray):
+        self.values = values  # sorted 1-D array of str/bytes
 
     def __len__(self) -> int:
         return len(self.values)
+
+    def __eq__(self, other) -> bool:
+        if not isinstance(other, Dictionary):
+            return NotImplemented
+        return len(self) == len(other) and bool(
+            np.all(self.values == other.values))
 
     def value(self, idx: int):
         return self.values[idx]
@@ -58,6 +63,73 @@ class Dictionary:
         if not np.all(self.values[pos_clip] == values):
             raise KeyError("unknown value(s) in lookup")
         return pos_clip.astype(np.int32)
+
+
+def blob_is_lossless(blob: np.ndarray, offsets: np.ndarray) -> bool:
+    """Whether each value of a UTF-8 blob decodes by itself.
+
+    `blob` (uint8) holds the values back to back, value i at
+    blob[offsets[i]:offsets[i + 1]].  An ASCII blob always does.  Otherwise
+    the blob must decode whole and every value start on a character, not
+    on a continuation byte (0b10xxxxxx): an invalid suffix of one value can
+    splice with an invalid prefix of the next into a valid sequence
+    (b"\\xc3" + b"\\xa9" == "é"), which the whole decode alone would pass.
+    """
+    if not len(blob) or blob.max() < 0x80:
+        return True
+    try:
+        str(blob, "utf-8")
+    except UnicodeDecodeError:
+        return False
+    starts = np.asarray(offsets[:-1])
+    starts = starts[starts < len(blob)]
+    return not ((blob[starts] & 0xC0) == 0x80).any()
+
+
+class BlobDictionary(Dictionary):
+    """A Dictionary kept as the native ingest's sorted UTF-8 value blob.
+
+    Building one Python string per distinct value costs about a
+    microsecond each, while a discovery reads only the few values its
+    output prints.  So a value is decoded when `value` asks for it, and
+    `values` decodes them all once, for the callers that need the array
+    (checkpoints, delta bundles, the serving index, id lookups).  The
+    caller vouches for `blob_is_lossless(blob, offsets)`.  `decoded`
+    counts the values decoded one at a time.
+    """
+
+    def __init__(self, blob: np.ndarray, offsets: np.ndarray):
+        self._blob = blob
+        self._offsets = offsets
+        self._values = None
+        self.decoded = 0
+
+    @property
+    def values(self) -> np.ndarray:
+        if self._values is None:
+            text = str(self._blob, "utf-8")
+            chars = np.asarray(self._offsets, np.int64)
+            if len(text) != len(self._blob):
+                # A value's characters lie between its byte offsets less
+                # the continuation bytes before them.
+                cont = (self._blob & 0xC0) == 0x80
+                before = np.concatenate([[0], np.cumsum(cont, dtype=np.int64)])
+                chars = chars - before[chars]
+            bounds = chars.tolist()
+            values = np.empty(len(self), object)
+            values[:] = [text[a:b] for a, b in zip(bounds[:-1], bounds[1:])]
+            self._values = values
+        return self._values
+
+    def __len__(self) -> int:
+        return len(self._offsets) - 1
+
+    def value(self, idx: int):
+        if self._values is not None:
+            return self._values[idx]
+        i = range(len(self))[idx]  # an array's negative and IndexError
+        self.decoded += 1
+        return str(self._blob[self._offsets[i]:self._offsets[i + 1]], "utf-8")
 
 
 def intern_triples(triples) -> tuple[np.ndarray, Dictionary]:

@@ -36,6 +36,9 @@ Semantics: identical ids/values to the Python path for valid-UTF-8 inputs
 native path is strictly more exact: it interns raw bytes (distinct byte
 strings stay distinct), while the Python reader's errors="replace" can
 conflate them; exported values are decoded with errors="replace" either way.
+The Dictionary of a blob whose every value is valid UTF-8 by itself is the
+blob (dictionary.BlobDictionary): a value is decoded when it is read, so a
+discovery decodes only the values its output prints.
 """
 
 from __future__ import annotations
@@ -47,7 +50,7 @@ import time
 
 import numpy as np
 
-from ..dictionary import Dictionary
+from ..dictionary import BlobDictionary, Dictionary, blob_is_lossless
 from ..obs import metrics, tracer
 
 _SO_PATH = os.environ.get("RDFIND_NATIVE_SO") or os.path.join(
@@ -255,43 +258,33 @@ def _read_stats(lib, h) -> dict:
     return out
 
 
-def _values_from_buffer(raw: bytes, offsets: np.ndarray):
+def _export_values(lib, h, n_values: int) -> tuple[np.ndarray, np.ndarray]:
+    """The engine's sorted value blob (uint8, filled in place, no copy) and
+    its n_values + 1 byte offsets."""
+    nbytes = int(lib.rdf_ingest_values_bytes(h))
+    buf = np.empty(max(nbytes, 1), np.uint8)
+    offsets = np.empty(n_values + 1, np.int64)
+    lib.rdf_ingest_get_values(h, buf.ctypes.data_as(ctypes.c_void_p),
+                              offsets.ctypes.data_as(ctypes.c_void_p))
+    return buf[:nbytes], offsets
+
+
+def _values_from_buffer(raw, offsets: np.ndarray):
     """Per-value UTF-8 decode of the exported dictionary blob.
 
-    Probes losslessness per value, not on the concatenated blob: an invalid
-    suffix of one value can splice with an invalid prefix of the next into a
-    valid sequence (b"\\xc3" + b"\\xa9" == "é"), so a whole-blob decode can
-    succeed while individual values are invalid.  Returns (values, lossless).
-
-    A blob that decodes whole is split without decoding each value: every
-    value is then valid exactly when it starts on a character, not on a
-    continuation byte (0b10xxxxxx), and its characters lie between its
-    byte offsets less the continuation bytes before them.
+    The ingest keeps a lossless blob as a :class:`BlobDictionary`; this
+    eager decode runs where some value is not valid UTF-8 by itself (and
+    for the multi-host sharded ingest), each such value decoded with
+    errors="replace".  Losslessness is probed per value, not on the
+    concatenated blob (see `blob_is_lossless`).  Returns (values, lossless).
     """
-    n_values = len(offsets) - 1
-    values = np.empty(n_values, object)
-    try:
-        text = raw.decode("utf-8")
-    except UnicodeDecodeError:
-        text = None
-    if text is not None and n_values:
-        offsets = np.asarray(offsets, np.int64)
-        if len(text) == len(raw):
-            chars = offsets
-        else:
-            cont = (np.frombuffer(raw, np.uint8) & 0xC0) == 0x80
-            before = np.concatenate([[0], np.cumsum(cont, dtype=np.int64)])
-            chars = offsets - before[offsets]
-            starts = offsets[:-1]
-            if cont[starts[starts < len(raw)]].any():
-                chars = None
-        if chars is not None:
-            bounds = chars.tolist()
-            values[:] = [text[a:b] for a, b in zip(bounds[:-1], bounds[1:])]
-            return values, True
+    blob = np.frombuffer(raw, np.uint8)
+    if blob_is_lossless(blob, offsets):
+        return BlobDictionary(blob, offsets).values, True
+    values = np.empty(len(offsets) - 1, object)
     lossless = True
-    for i in range(n_values):
-        chunk = raw[offsets[i]:offsets[i + 1]]
+    for i in range(len(values)):
+        chunk = blob[offsets[i]:offsets[i + 1]].tobytes()
         try:
             values[i] = chunk.decode("utf-8")
         except UnicodeDecodeError:
@@ -310,6 +303,16 @@ def canonicalize(ids: np.ndarray, values: np.ndarray, lossless: bool):
     uniques, inverse = np.unique(values, return_inverse=True)
     ids = inverse.astype(np.int32)[ids]
     return ids, Dictionary(uniques)
+
+
+def native_dictionary(ids: np.ndarray, blob: np.ndarray, offsets: np.ndarray):
+    """(ids, Dictionary) of the native engine's sorted value blob: the blob
+    itself when every value is valid UTF-8 by itself, so that no value is
+    decoded until asked for; else every value decoded and canonicalized."""
+    if blob_is_lossless(blob, offsets):
+        return ids, BlobDictionary(blob, offsets)
+    values, lossless = _values_from_buffer(blob, offsets)
+    return canonicalize(ids, values, lossless)
 
 
 class IngestStream:
@@ -387,15 +390,9 @@ class IngestStream:
         self._n_values = int(n_values)
         return remaps
 
-    def raw_values(self) -> tuple[bytes, np.ndarray]:
-        """(concatenated byte blob, offsets) of the sorted distinct values."""
-        nbytes = int(self._lib.rdf_ingest_values_bytes(self._h))
-        buf = np.empty(max(nbytes, 1), np.uint8)
-        offsets = np.empty(self._n_values + 1, np.int64)
-        self._lib.rdf_ingest_get_values(
-            self._h, buf.ctypes.data_as(ctypes.c_void_p),
-            offsets.ctypes.data_as(ctypes.c_void_p))
-        return buf.tobytes()[:nbytes], offsets
+    def raw_values(self) -> tuple[np.ndarray, np.ndarray]:
+        """(concatenated uint8 blob, offsets) of the sorted distinct values."""
+        return _export_values(self._lib, self._h, self._n_values)
 
     def decoded_values(self):
         """(values, lossless): the sorted distinct values, UTF-8-decoded
@@ -469,10 +466,9 @@ def _ingest_parallel(paths, tabs, expect_quad, skip_comments, threads,
                 t0 = time.perf_counter()
                 ids = asm.finalize(remaps)
                 remap_ms = (time.perf_counter() - t0) * 1000.0
-            raw, offsets = stream.raw_values()
+            blob, offsets = stream.raw_values()
             st = stream.stats()
-        values, lossless = _values_from_buffer(raw, offsets)
-        ids, dictionary = canonicalize(ids, values, lossless)
+        ids, dictionary = native_dictionary(ids, blob, offsets)
     if stats is not None:
         st["remap_ms"] += remap_ms  # host-side block rewrite rides the phase
         publish_stats(stats, st, ids.shape[0], len(dictionary), t_wall)
@@ -499,17 +495,11 @@ def _ingest_serial(paths, tabs, expect_quad, skip_comments, stats):
         ids = np.empty((n_triples, 3), np.int32)
         if n_triples:
             lib.rdf_ingest_get_triples(h, ids.ctypes.data_as(ctypes.c_void_p))
-        nbytes = lib.rdf_ingest_values_bytes(h)
-        buf = np.empty(max(nbytes, 1), np.uint8)
-        offsets = np.empty(n_values + 1, np.int64)
-        lib.rdf_ingest_get_values(
-            h, buf.ctypes.data_as(ctypes.c_void_p),
-            offsets.ctypes.data_as(ctypes.c_void_p))
+        blob, offsets = _export_values(lib, h, n_values)
         st = _read_stats(lib, h)
     finally:
         lib.rdf_ingest_free(h)
-    values, lossless = _values_from_buffer(buf.tobytes()[:nbytes], offsets)
-    ids, dictionary = canonicalize(ids, values, lossless)
+    ids, dictionary = native_dictionary(ids, blob, offsets)
     if stats is not None:
         publish_stats(stats, st, ids.shape[0], len(dictionary), t_wall)
     return ids, dictionary

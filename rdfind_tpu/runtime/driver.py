@@ -20,7 +20,7 @@ import time
 import numpy as np
 
 from ..data import CindTable
-from ..dictionary import Dictionary, intern_triples
+from ..dictionary import BlobDictionary, Dictionary, intern_triples
 from ..io import native, ntriples, prefixes, reader
 from ..models import allatonce, approximate, late_bb, sharded, small_to_large
 from ..obs import memory as obs_memory
@@ -1226,6 +1226,12 @@ def _emit_sinks(cfg: Config, phases: _Phases, counters: dict, table,
                     extra=extra)
                 integrity.write_certificate(dest, cert)
             phases.run("write-certificate", write_cert)
+
+    # Whether the dictionary stayed the native ingest's value blob, and how
+    # many values the sinks decoded from it one at a time.
+    lazy = isinstance(dictionary, BlobDictionary)
+    counters["dictionary-lazy"] = int(lazy)
+    counters["dictionary-decoded"] = dictionary.decoded if lazy else 0
 
 
 def _report(cfg: Config, counters: dict, timings: dict) -> None:

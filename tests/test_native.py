@@ -5,7 +5,7 @@ import gzip
 import numpy as np
 import pytest
 
-from rdfind_tpu.dictionary import intern_triples
+from rdfind_tpu.dictionary import BlobDictionary, Dictionary, intern_triples
 from rdfind_tpu.io import native, ntriples, reader
 
 pytestmark = pytest.mark.skipif(not native.available(),
@@ -147,3 +147,84 @@ def test_values_from_buffer_matches_per_value_decode(values):
         except UnicodeDecodeError:
             valid = False
     assert lossless == valid
+
+
+@pytest.mark.parametrize("values", [
+    [b"<a>", b"<b>", b"lit"],
+    [b"ab", "héllo".encode(), "€x".encode()],
+    [b"", b"x", b"y"],
+    [b"only"],
+    [],
+    [b"a\xc3", b"\xa9b"],
+], ids=["ascii", "multi-byte", "empty-value", "one-value", "empty",
+        "spliced"])
+def test_blob_dictionary_matches_eager(values):
+    """The ingest keeps a lossless blob as a BlobDictionary, which answers
+    as the eager decode's Dictionary does; a blob whose values are not each
+    valid UTF-8 (the splice) takes the eager, canonicalized path."""
+    blob = np.frombuffer(b"".join(values), np.uint8)
+    offsets = np.cumsum([0] + [len(v) for v in values]).astype(np.int64)
+    ids = np.arange(len(values), dtype=np.int32)
+    got_ids, got = native.native_dictionary(ids, blob, offsets)
+    want_ids, want = native.canonicalize(
+        ids, *native._values_from_buffer(blob, offsets))
+    lazy = isinstance(got, BlobDictionary)
+    assert lazy == (values[-1:] != [b"\xa9b"])
+    np.testing.assert_array_equal(got_ids, want_ids)
+    assert len(got) == len(want)
+    assert [got.value(i) for i in range(len(got))] == list(want.values)
+    if lazy:
+        assert got.decoded == len(values)
+        if values:
+            assert got.value(-1) == want.value(-1)
+        with pytest.raises(IndexError):
+            got.value(len(values))
+    assert got.values.dtype == object
+    assert list(got.values) == list(want.values)
+    for v in want.values:
+        assert got.id(v) == want.id(v)
+    np.testing.assert_array_equal(got.ids(want.values), want.ids(want.values))
+    for d in (got, want):
+        with pytest.raises(KeyError):
+            d.id("<missing>")
+        if len(d):
+            with pytest.raises(KeyError):
+                d.ids(["<missing>"])
+    assert got == want and want == got
+    assert got != Dictionary(np.array(["<other>"], object))
+
+
+PEOPLE = ("<alice> <bornIn> <berlin> .\n<bob> <bornIn> <berlin> .\n"
+          "<zoë> <bornIn> <paris> .\n<alice> <livesIn> <berlin> .\n"
+          "<bob> <livesIn> <berlin> .\n<zoë> <livesIn> <paris> .\n"
+          "<dave> <livesIn> <rome> .\n").encode()
+
+
+@pytest.mark.parametrize("data, lazy", [
+    (PEOPLE, 1),
+    (PEOPLE.replace("ë".encode(), b"\xeb"), 0),  # Latin-1: invalid UTF-8
+], ids=["utf8", "invalid-utf8"])
+def test_driver_native_output_matches_python_ingest(tmp_path, data, lazy):
+    """A native-ingest run writes the --output of a Python-ingest run; its
+    dictionary stays the value blob when the input is valid UTF-8, and the
+    output then decodes at most the four values of each CIND row."""
+    from rdfind_tpu.programs import rdfind
+
+    f = tmp_path / "people.nt"
+    f.write_bytes(data)
+    outputs, counters = [], []
+    for flags in ([], ["--no-native-ingest"]):
+        out = tmp_path / f"cinds{len(outputs)}.txt"
+        got = []
+        assert rdfind.main([str(f), "--support", "2", "--clean-implied",
+                            "--output", str(out), *flags],
+                           on_result=got.append) == 0
+        outputs.append(out.read_text())
+        counters.append(got[0].counters)
+    assert outputs[0] == outputs[1]
+    rows = outputs[0].count("\n")
+    assert rows > 0
+    assert counters[0]["dictionary-lazy"] == lazy
+    decoded = counters[0]["dictionary-decoded"]
+    assert 0 < decoded <= 6 * rows if lazy else decoded == 0
+    assert counters[1]["dictionary-lazy"] == 0
